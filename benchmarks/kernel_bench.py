@@ -98,8 +98,8 @@ def _kernel_errs(interpret: bool = True) -> dict:
 
     # paged decode: pool + shuffled block tables + ragged lengths
     bs, mb = 16, 4
-    kp = jax.random.normal(ks[6], (1 + 2 * mb, bs, 2, 64))
-    vp = jax.random.normal(ks[7], (1 + 2 * mb, bs, 2, 64))
+    kp = jax.random.normal(ks[6], (1 + 2 * mb, 2, bs, 64))
+    vp = jax.random.normal(ks[7], (1 + 2 * mb, 2, bs, 64))
     rng = np.random.default_rng(0)
     tables = jnp.asarray(1 + rng.permutation(2 * mb).reshape(2, mb)
                          .astype(np.int32))

@@ -113,7 +113,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry as arch_registry
-from repro.core.power import tpu_serving_report
+from repro.core.power import serving_power_report
 from repro.models.registry import fns_for
 from repro.serving.engine import Request, ServeStats, ServingEngine
 from repro.serving.faults import FaultPlan, FaultSpec
@@ -856,12 +856,14 @@ def run(verbose: bool = True, repeats: int = 3) -> dict:
             stats = replicas[0].serve(_requests(cfg, 16))
         else:
             stats = MultiReplicaEngine(replicas).serve(_requests(cfg, 16))
-        rep = tpu_serving_report(stats.tokens_per_s, chips=n_rep)
+        # None off TPU: a CPU run's power is not measured
+        rep = serving_power_report(stats.tokens_per_s,
+                                   [jax.devices()[0]] * n_rep)
         out[f"replicas_{n_rep}"] = dict(
-            _summary(stats), tokens_per_s_per_w=rep.items_per_watt)
+            _summary(stats),
+            tokens_per_s_per_w=rep.items_per_watt if rep else None)
         if verbose:
             print(f"serving x{n_rep}: {stats.tokens_per_s:.1f} tok/s  "
-                  f"{rep.items_per_watt:.4f} tok/s/W  "
                   f"occ={stats.slot_occupancy:.2f}")
     out["replica_scaling_2x"] = (out["replicas_2"]["tokens_per_s"]
                                  / out["replicas_1"]["tokens_per_s"])

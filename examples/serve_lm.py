@@ -58,7 +58,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry as arch_registry
-from repro.core.power import tpu_serving_report
+from repro.core.power import power_row, serving_power_report
 from repro.models.registry import fns_for
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.faults import FaultPlan
@@ -186,7 +186,9 @@ def main():
               f"failed={stats.requests_failed}  "
               f"retried={stats.requests_retried}  "
               f"replica_failures={stats.replica_failures}")
-    print(tpu_serving_report(stats.tokens_per_s, chips=args.replicas).row())
+    print(power_row(serving_power_report(stats.tokens_per_s,
+                                         [e.device or jax.devices()[0]
+                                          for e in replicas])))
     for r in reqs[:3]:
         ttft = f"{r.ttft_s:.2f}s" if r.ttft_s is not None else "n/a"
         print(f"  req {r.rid} [{r.state.value}]: {r.output}  ttft={ttft}")

@@ -1,15 +1,16 @@
 """Power accounting (paper §5, Eq. 1): Throughput_Watt = (items/s) / TDP.
 
-TDP models for the paper's devices and for the TPU v5e target live in
-`repro.roofline.hw`; this module turns offload/benchmark stats into the
-paper's img/W metric and the LM-serving analogues (tokens/s/W, tokens/J).
+TDP models for the paper's devices and for the TPU chips (keyed by JAX's
+``device_kind``) live in `repro.roofline.hw`; this module turns
+offload/benchmark stats into the paper's img/W metric and the LM-serving
+analogues (tokens/s/W, tokens/J).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.roofline.hw import (CHIPS, MYRIAD2_VPU, NCS_STICK_PEAK_WATTS,
-                               QUADRO_K4000, TPU_V5E, XEON_E5_2609V2, ChipSpec)
+from repro.roofline.hw import (MYRIAD2_VPU, QUADRO_K4000, XEON_E5_2609V2,
+                               chip_for)
 
 # Paper-calibrated single-inference latencies (Fig 6b normalization bases).
 PAPER_LATENCY_S = {
@@ -62,14 +63,27 @@ class PowerReport:
 def report(device: str, n_devices: int, items_per_s: float,
            *, per_device_watts: float | None = None) -> PowerReport:
     if per_device_watts is None:
-        per_device_watts = PAPER_TDP_W.get(device, TPU_V5E.tdp_watts)
+        per_device_watts = PAPER_TDP_W[device]
     return PowerReport(device=device, n_devices=n_devices,
                        items_per_s=items_per_s,
                        tdp_watts_total=per_device_watts * n_devices)
 
 
-def tpu_serving_report(tokens_per_s: float, chips: int) -> PowerReport:
-    """LM-serving analogue of the paper's metric on the v5e target."""
-    return PowerReport(device=TPU_V5E.name, n_devices=chips,
+def serving_power_report(tokens_per_s: float,
+                         devices) -> PowerReport | None:
+    """LM-serving analogue of the paper's metric over the distinct chips
+    that served (replicas sharing a chip count it once); None when they
+    are not TPUs, whose power is not modelled."""
+    devices = set(devices)
+    chip = chip_for(next(iter(devices)))      # one host holds one kind
+    if chip is None:
+        return None
+    return PowerReport(device=chip.name, n_devices=len(devices),
                        items_per_s=tokens_per_s,
-                       tdp_watts_total=TPU_V5E.tdp_watts * chips)
+                       tdp_watts_total=chip.tdp_watts * len(devices))
+
+
+def power_row(report: PowerReport | None) -> str:
+    """The report's row, or the line saying power was not measured."""
+    return (report.row() if report is not None
+            else "power: not measured (no TPU)")

@@ -15,6 +15,13 @@ sequence length, not the worst-case ``max_len``.  Blocks past ``length``
 are skipped outright (`pl.when`), and an int8 pool is dequantized in-VMEM
 from per-row absmax scales.
 
+The pool is laid out ``(N, K, bs, D)``: one grid step DMAs the ``(bs, D)``
+tile of one kv-head of one block, whose trailing dims are (8, 128)-aligned
+as Mosaic requires.  The int8 scales ``(N, K, bs)`` come in whole per block
+(``(K, bs)`` equals the array's trailing dims); the kv-head's row of them
+scales the score and probability tiles along their key axis, which equals
+dequantizing K and V row by row.
+
 Oracle: `models.layers.attention.chunked_attention` with kv_len masking
 (`ref.decode_attention_ref` / `ref.paged_decode_attention_ref`).
 """
@@ -113,6 +120,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     ib = pl.program_id(2)
 
     @pl.when(ib == 0)
@@ -128,15 +136,12 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
     @pl.when(ib * bs < valid)
     def _update():
         q = q_ref[0, 0, :, :]                     # (G, D)
-        k = k_ref[0, :, 0, :]                     # (bs, D)
-        v = v_ref[0, :, 0, :]
-        if quant:
-            k = (k.astype(jnp.float32)
-                 * ks_ref[0, :, 0][:, None]).astype(q.dtype)
-            v = (v.astype(jnp.float32)
-                 * vs_ref[0, :, 0][:, None]).astype(q.dtype)
+        k = k_ref[0, 0, :, :].astype(q.dtype)     # (bs, D)
+        v = v_ref[0, 0, :, :].astype(q.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * ks_ref[0, pl.ds(h, 1), :]     # (1, bs) row scales
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
         k_pos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -148,6 +153,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         p = jnp.exp(s - m_safe)
         corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        if quant:
+            p = p * vs_ref[0, pl.ds(h, 1), :]
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -166,16 +173,16 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                            v_scale: jax.Array | None = None,
                            softcap: float = 0.0,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, D); k_pool/v_pool: (N, bs, K, D) global block pool;
+    """q: (B, H, D); k_pool/v_pool: (N, K, bs, D) global block pool;
     block_tables: (B, max_blocks) physical block per logical block;
-    lengths: (B,) valid rows; k_scale/v_scale: (N, bs, K) for int8 pools.
+    lengths: (B,) valid rows; k_scale/v_scale: (N, K, bs) for int8 pools.
 
     Returns (B, H, D).  Grid (B, K, max_blocks); the block table is a
     scalar-prefetch operand so the k/v BlockSpec index maps dereference it
     to DMA each sequence's physical blocks in logical order.
     """
     B, H, D = q.shape
-    N, bs, K, _ = k_pool.shape
+    N, K, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
     G = H // K
     scale = 1.0 / (D ** 0.5)
@@ -186,20 +193,20 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         return (b, h, 0, 0)
 
     def kv_map(b, h, ib, bt_ref, len_ref):
-        return (bt_ref[b, ib], 0, h, 0)
+        return (bt_ref[b, ib], h, 0, 0)
 
     def sc_map(b, h, ib, bt_ref, len_ref):
-        return (bt_ref[b, ib], 0, h)
+        return (bt_ref[b, ib], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, G, D), q_map),
-        pl.BlockSpec((1, bs, 1, D), kv_map),
-        pl.BlockSpec((1, bs, 1, D), kv_map),
+        pl.BlockSpec((1, 1, bs, D), kv_map),
+        pl.BlockSpec((1, 1, bs, D), kv_map),
     ]
     args = [qg, k_pool, v_pool]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), sc_map),
-                     pl.BlockSpec((1, bs, 1), sc_map)]
+        in_specs += [pl.BlockSpec((1, K, bs), sc_map),
+                     pl.BlockSpec((1, K, bs), sc_map)]
         args += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -24,16 +24,16 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, *,
                                chunk=1024):
     """Paged oracle: gather blocks into logical order, then dense decode.
 
-    q: (B, H, D); k_pool/v_pool: (N, bs, K, D) global pool; block_tables:
+    q: (B, H, D); k_pool/v_pool: (N, K, bs, D) global pool; block_tables:
     (B, max_blocks) physical block ids per logical block; lengths: (B,)
-    valid rows per sequence.  k_scale/v_scale: (N, bs, K) when the pool is
+    valid rows per sequence.  k_scale/v_scale: (N, K, bs) when the pool is
     int8 (absmax-dequantized to q.dtype before attending, matching the
     dense quantized-cache path bit for bit).
     """
     B, H, D = q.shape
-    N, bs, K, _ = k_pool.shape
+    N, K, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
-    k = k_pool[block_tables]                     # (B, mb, bs, K, D)
+    k = k_pool[block_tables]                     # (B, mb, K, bs, D)
     v = v_pool[block_tables]
     if k_scale is not None:
         k = (k.astype(jnp.float32)
@@ -41,8 +41,8 @@ def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths, *,
         v = (v.astype(jnp.float32)
              * v_scale[block_tables][..., None]).astype(q.dtype)
     S = mb * bs
-    k = k.reshape(B, S, K, D).astype(q.dtype)
-    v = v.reshape(B, S, K, D).astype(q.dtype)
+    k = k.swapaxes(2, 3).reshape(B, S, K, D).astype(q.dtype)
+    v = v.swapaxes(2, 3).reshape(B, S, K, D).astype(q.dtype)
     out = chunked_attention(
         q[:, None], k, v, causal=False,
         q_positions=jnp.zeros((B, 1), jnp.int32),

@@ -1,10 +1,13 @@
 """Kernel backend dispatch.
 
-Pallas kernels target TPU; on this CPU-only container they execute in
-``interpret=True`` mode (Python evaluation of the kernel body), which is
-correct but slow — so the model layers default to their jnp oracles and
-kernels are opt-in (``enable_pallas()``), becoming the default on a real
-TPU backend.
+Pallas kernels target TPU.  On a TPU backend the model layers call the
+compiled kernels; on any other backend (the CPU test runs,
+``JAX_PLATFORMS=cpu``) they call their jnp oracles, and a kernel forced on
+with ``enable_pallas()`` runs in ``interpret=True`` mode (Python
+evaluation of the kernel body: correct, slow, and blind to what Mosaic
+would refuse — `tests/test_tpu_compile.py` compiles the paged kernels
+for a described v5e to cover that).  `chip_smoke.py` checks that the
+compiled serving steps hold the kernels (``tpu_custom_call``).
 
 Each kernel family's ops module registers its (pallas, ref) pair in the
 kernel table via :func:`register_kernel` (backend selection itself lives

@@ -13,11 +13,11 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, q_start,
     """Multi-row query chunk vs block-table-gathered pool KV.
 
     q: (B, C, H, D) — a prompt chunk whose row ``o`` sits at absolute
-    position ``q_start[b] + o``; k_pool/v_pool: (N, bs, K, D) global pool;
+    position ``q_start[b] + o``; k_pool/v_pool: (N, K, bs, D) global pool;
     block_tables: (B, max_blocks) physical block per logical block;
     q_start: (B,) first query position; lengths: (B,) total valid KV rows
     *including* this chunk's (the chunk's own rows are already written to
-    the pool before attending).  k_scale/v_scale: (N, bs, K) for int8
+    the pool before attending).  k_scale/v_scale: (N, K, bs) for int8
     pools (absmax-dequantized before attending, matching the decode path).
 
     Causality makes row ``o`` attend to every seeded/earlier row plus the
@@ -26,9 +26,9 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, q_start,
     Returns (B, C, H, D).
     """
     B, C, H, D = q.shape
-    N, bs, K, _ = k_pool.shape
+    N, K, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
-    k = k_pool[block_tables]                     # (B, mb, bs, K, D)
+    k = k_pool[block_tables]                     # (B, mb, K, bs, D)
     v = v_pool[block_tables]
     if k_scale is not None:
         k = (k.astype(jnp.float32)
@@ -36,8 +36,8 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, q_start,
         v = (v.astype(jnp.float32)
              * v_scale[block_tables][..., None]).astype(q.dtype)
     S = mb * bs
-    k = k.reshape(B, S, K, D).astype(q.dtype)
-    v = v.reshape(B, S, K, D).astype(q.dtype)
+    k = k.swapaxes(2, 3).reshape(B, S, K, D).astype(q.dtype)
+    v = v.swapaxes(2, 3).reshape(B, S, K, D).astype(q.dtype)
     q_pos = q_start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
     out = chunked_attention(
         q, k, v, causal=True, q_positions=q_pos,

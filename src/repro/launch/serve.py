@@ -33,28 +33,39 @@ Example:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
       --replicas 2 --inject-faults replica.executor:raise:4 \
       --max-retries 2 --deadline-s 30
+
+Weights are initialised from ``PRNGKey(0)`` in the model's compute dtype
+(bf16: qwen2.5-3b's 3.1B parameters take 6.2 GB, so one 16 GB chip holds
+them with room for KV).  Replica ``i`` is placed on ``jax.devices()[i]``
+(round-robin when there are more replicas than devices).  The exit code
+is non-zero when any request ends FAILED.  `chip_smoke.py` at the repo
+root builds its fleets through :func:`build`.
 """
 from __future__ import annotations
 
 import argparse
+import sys
+from dataclasses import dataclass
 
 import jax
 import numpy as np
 
 from repro.configs import registry as arch_registry
-from repro.core.power import tpu_serving_report
+from repro.core.power import power_row, serving_power_report
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import fns_for
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServeStats, ServingEngine
 from repro.serving.faults import FaultPlan
 from repro.serving.router import ReplicaRouter
 from repro.serving.sampler import greedy, temperature
+from repro.serving.scheduler import RequestState
 
 
 def _fmt_ms(v: float | None) -> str:
     return f"{v * 1e3:.1f}ms" if v is not None else "n/a"
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -161,16 +172,102 @@ def main() -> int:
                     default="continuous",
                     help="wave = legacy lock-step decode (single replica "
                          "only), for A/B comparison")
-    args = ap.parse_args()
+    return ap
+
+
+@dataclass
+class Fleet:
+    """The replicas a launch built, and the router in front of them when
+    there are several."""
+    cfg: object
+    engines: list[ServingEngine]
+    router: ReplicaRouter | None
+
+    @property
+    def devices(self) -> list:
+        return [e.device for e in self.engines]
+
+    def serve(self, reqs: list[Request], mode: str = "continuous"
+              ) -> ServeStats:
+        if self.router is not None:
+            return self.router.serve(reqs)
+        if mode == "wave":
+            return self.engines[0].serve_wave(reqs)
+        return self.engines[0].serve(reqs)
+
+
+def check_args(ap: argparse.ArgumentParser, args) -> None:
+    """Reject flag combinations the fleet cannot serve."""
     if args.mode == "wave" and args.replicas > 1:
         ap.error("--mode wave is the single-replica legacy baseline; "
                  "drop --replicas or use --mode continuous")
+    if args.draft_model and not args.no_spec and args.contiguous_kv:
+        ap.error("--draft-model needs the paged KV pool; drop --contiguous-kv")
+    roles = _roles(args)
+    if len(roles) != args.replicas:
+        ap.error(f"--replica-roles names {len(roles)} roles for "
+                 f"--replicas {args.replicas}")
+    if args.replicas == 1 and roles != ["mixed"]:
+        ap.error("--replica-roles needs --replicas > 1 (a lone prefill "
+                 "replica has nowhere to migrate blocks)")
 
-    cfg = (arch_registry.smoke(args.arch) if args.smoke
-           else arch_registry.config(args.arch))
-    fns = fns_for(cfg)
-    params = fns.init(cfg, jax.random.PRNGKey(0))
-    max_len = args.prompt_len + args.new_tokens + 1
+
+def _roles(args) -> list[str]:
+    return (args.replica_roles.split(",") if args.replica_roles
+            else ["mixed"] * args.replicas)
+
+
+def init_model(arch: str, smoke: bool, seed: int = 0):
+    """Config and seeded weights, initialised in the compute dtype by one
+    jitted program: run op by op, each float32 draw would sit in device
+    memory beside its cast (15.3 GB peak for qwen2.5-3b on a 16 GB v5e)."""
+    cfg = arch_registry.smoke(arch) if smoke else arch_registry.config(arch)
+    cfg = cfg.replace(param_dtype=cfg.compute_dtype)
+    init = jax.jit(fns_for(cfg).init, static_argnums=0)
+    return cfg, init(cfg, jax.random.PRNGKey(seed))
+
+
+def build(args, model=None) -> Fleet:
+    """Replicas (one per device) and router for parsed ``args``, serving
+    ``model`` — a ``(cfg, params)`` pair from :func:`init_model`, made here
+    when not given.  Params are initialised once; each replica commits its
+    own copy and its KV state to its device."""
+    cfg, params = model or init_model(args.arch, args.smoke)
+    fault_plan = (FaultPlan.parse(args.inject_faults)
+                  if args.inject_faults else None)
+    kw = dict(max_len=args.prompt_len + args.new_tokens + 1,
+              batch_slots=args.slots,
+              paged=False if args.contiguous_kv else None,
+              pool_blocks=args.kv_pool_blocks,
+              preemption=not args.no_preemption,
+              prefix_sharing=not args.no_prefix_sharing,
+              prefill_chunk=args.prefill_chunk,
+              seeded_prefill=not args.no_seeded_prefill,
+              host_blocks=0 if args.no_kv_tiering else args.host_blocks,
+              fault_plan=fault_plan)
+    if args.draft_model and not args.no_spec:
+        if args.draft_model == args.arch:
+            draft_cfg, draft_params = cfg, params   # self-speculation
+        else:
+            draft_cfg, draft_params = init_model(args.draft_model, args.smoke,
+                                                 seed=1)
+        kw.update(draft_cfg=draft_cfg, draft_params=draft_params,
+                  spec_k=args.spec_k)
+    devices = jax.devices()
+    roles = _roles(args)
+    if args.replicas == 1:
+        return Fleet(cfg, [ServingEngine(cfg, params, device=devices[0], **kw)],
+                     None)
+    engines = [ServingEngine(cfg, params, name=f"replica{i}", role=roles[i],
+                             device=devices[i % len(devices)], **kw)
+               for i in range(args.replicas)]
+    router = ReplicaRouter(engines, affinity=not args.no_affinity,
+                           steal=not args.no_steal,
+                           max_retries=args.max_retries)
+    return Fleet(cfg, engines, router)
+
+
+def make_requests(args, cfg) -> list[Request]:
     rng = np.random.default_rng(0)
     mk_sampler = (greedy if args.temperature == 0
                   else lambda: temperature(args.temperature, top_k=40))
@@ -186,51 +283,17 @@ def main() -> int:
     if args.deadline_s is not None:
         for r in reqs:
             r.deadline_s = args.deadline_s
-    fault_plan = (FaultPlan.parse(args.inject_faults)
-                  if args.inject_faults else None)
+    return reqs
 
-    kw = dict(max_len=max_len, batch_slots=args.slots,
-              paged=False if args.contiguous_kv else None,
-              pool_blocks=args.kv_pool_blocks,
-              preemption=not args.no_preemption,
-              prefix_sharing=not args.no_prefix_sharing,
-              prefill_chunk=args.prefill_chunk,
-              seeded_prefill=not args.no_seeded_prefill,
-              host_blocks=0 if args.no_kv_tiering else args.host_blocks,
-              fault_plan=fault_plan)
-    if args.draft_model and not args.no_spec:
-        if args.contiguous_kv:
-            ap.error("--draft-model needs the paged KV pool; "
-                     "drop --contiguous-kv")
-        if args.draft_model == args.arch:
-            draft_cfg, draft_params = cfg, params   # self-speculation
-        else:
-            draft_cfg = (arch_registry.smoke(args.draft_model) if args.smoke
-                         else arch_registry.config(args.draft_model))
-            draft_params = fns_for(draft_cfg).init(draft_cfg,
-                                                   jax.random.PRNGKey(1))
-        kw.update(draft_cfg=draft_cfg, draft_params=draft_params,
-                  spec_k=args.spec_k)
-    roles = (args.replica_roles.split(",") if args.replica_roles
-             else ["mixed"] * args.replicas)
-    if len(roles) != args.replicas:
-        ap.error(f"--replica-roles names {len(roles)} roles for "
-                 f"--replicas {args.replicas}")
-    if args.replicas == 1 and roles != ["mixed"]:
-        ap.error("--replica-roles needs --replicas > 1 (a lone prefill "
-                 "replica has nowhere to migrate blocks)")
-    if args.replicas > 1:
-        replicas = [ServingEngine(cfg, params, name=f"replica{i}",
-                                  role=roles[i], **kw)
-                    for i in range(args.replicas)]
-        router = ReplicaRouter(replicas, affinity=not args.no_affinity,
-                               steal=not args.no_steal,
-                               max_retries=args.max_retries)
-        stats = router.serve(reqs)
-    else:
-        eng = ServingEngine(cfg, params, **kw)
-        stats = (eng.serve_wave(reqs) if args.mode == "wave"
-                 else eng.serve(reqs))
+
+def main(argv: list[str] | None = None) -> int:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    check_args(ap, args)
+    enable_compile_cache()
+    fleet = build(args)
+    reqs = make_requests(args, fleet.cfg)
+    stats = fleet.serve(reqs, args.mode)
     print(f"requests={stats.requests} tokens={stats.tokens} "
           f"wall={stats.wall_s:.2f}s tok/s={stats.tokens_per_s:.2f}")
     print(f"ttft p50={_fmt_ms(stats.ttft_p50_s)} "
@@ -281,8 +344,11 @@ def main() -> int:
         print(f"preemptions={stats.preemptions}  "
               f"prefix_shared_blocks={stats.prefix_shared_blocks}  "
               f"slo_miss_rate={miss}")
-    report = tpu_serving_report(stats.tokens_per_s, chips=args.replicas)
-    print(report.row())
+    print(power_row(serving_power_report(stats.tokens_per_s, fleet.devices)))
+    failed = [r.rid for r in reqs if r.state is RequestState.FAILED]
+    if failed:
+        print(f"FAILED requests: {failed}", file=sys.stderr)
+        return 1
     return 0
 
 

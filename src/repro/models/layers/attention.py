@@ -29,11 +29,17 @@ def attention_table(cfg, d_model: int | None = None):
     """Parameter table for one attention block."""
     d = d_model or cfg.d_model
     hd = cfg.resolved_head_dim
+    # 1/sqrt(fan_in) over the contracted dims: the default would take the
+    # fan-in from the heads axis, making scores hot enough that the
+    # softmax picks single keys and rounding flips outputs layer by layer
+    s_in, s_out = d ** -0.5, (cfg.num_heads * hd) ** -0.5
     t = {
-        "wq": weight((d, cfg.num_heads, hd), ("embed", "heads", None)),
-        "wk": weight((d, cfg.num_kv_heads, hd), ("embed", "kv_heads", None)),
-        "wv": weight((d, cfg.num_kv_heads, hd), ("embed", "kv_heads", None)),
-        "wo": weight((cfg.num_heads, hd, d), ("heads", None, "embed")),
+        "wq": weight((d, cfg.num_heads, hd), ("embed", "heads", None), s_in),
+        "wk": weight((d, cfg.num_kv_heads, hd), ("embed", "kv_heads", None),
+                     s_in),
+        "wv": weight((d, cfg.num_kv_heads, hd), ("embed", "kv_heads", None),
+                     s_in),
+        "wo": weight((cfg.num_heads, hd, d), ("heads", None, "embed"), s_out),
     }
     if cfg.qkv_bias:
         t["bq"] = bias((cfg.num_heads, hd), ("heads", None))

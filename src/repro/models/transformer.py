@@ -61,9 +61,11 @@ class PagedKVCache(NamedTuple):
     """Paged KV cache: one global pool of fixed-size blocks shared by every
     decode slot, indexed through per-slot block tables (vLLM-style).
 
-    k/v: (L, N_blocks, block_size, K, D) — block 0 is the trash block that
-    retired slots write into; block_tables: (B, max_blocks) physical block
-    id per logical block, 0 where unassigned; length: (B,) valid KV rows.
+    k/v: (L, N_blocks, K, block_size, D) — each (block, kv-head) holds a
+    (block_size, D) tile, the unit the Pallas kernels DMA; block 0 is the
+    trash block that retired slots write into; block_tables: (B,
+    max_blocks) physical block id per logical block, 0 where unassigned;
+    length: (B,) valid KV rows.
     """
     k: jax.Array
     v: jax.Array
@@ -72,18 +74,18 @@ class PagedKVCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def max_len(self) -> int:
         """Max addressable rows per sequence (table width x block size)."""
-        return self.block_tables.shape[1] * self.k.shape[2]
+        return self.block_tables.shape[1] * self.k.shape[3]
 
 
 class QuantPagedKVCache(NamedTuple):
     """int8 variant of :class:`PagedKVCache`: pools are int8 with absmax
-    scales per (block, row, kv-head).  k/v: (L, N, bs, K, D) int8;
-    k_scale/v_scale: (L, N, bs, K) f32."""
+    scales per (block, kv-head, row).  k/v: (L, N, K, bs, D) int8;
+    k_scale/v_scale: (L, N, K, bs) f32."""
     k: jax.Array
     v: jax.Array
     k_scale: jax.Array
@@ -93,11 +95,11 @@ class QuantPagedKVCache(NamedTuple):
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def max_len(self) -> int:
-        return self.block_tables.shape[1] * self.k.shape[2]
+        return self.block_tables.shape[1] * self.k.shape[3]
 
 
 def quantize_kv(x: jax.Array):
@@ -136,7 +138,7 @@ def make_paged_cache(cfg, num_blocks: int, block_size: int, batch: int,
     with ``batch`` block tables of ``max_blocks`` entries each."""
     L = num_layers if num_layers is not None else cfg.num_layers
     hd = cfg.resolved_head_dim
-    shape = (L, num_blocks, block_size, cfg.num_kv_heads, hd)
+    shape = (L, num_blocks, cfg.num_kv_heads, block_size, hd)
     tables = jnp.zeros((batch, max_blocks), jnp.int32)
     ln = jnp.zeros((batch,), jnp.int32)
     if dtype == "int8":
@@ -158,11 +160,11 @@ def scatter_prefill_blocks(cache, dense: KVCache, ids: jax.Array):
     past the prompt's blocks point at the trash block 0, so bucket padding
     rows land in trash).  Returns the cache with the pools updated.
     """
-    L, N, bs, K, D = cache.k.shape
+    L, N, K, bs, D = cache.k.shape
     S = dense.k.shape[2]
     nb = S // bs
-    kb = dense.k[:, 0].reshape(L, nb, bs, K, D)
-    vb = dense.v[:, 0].reshape(L, nb, bs, K, D)
+    kb = dense.k[:, 0].reshape(L, nb, bs, K, D).swapaxes(2, 3)
+    vb = dense.v[:, 0].reshape(L, nb, bs, K, D).swapaxes(2, 3)
     if isinstance(cache, QuantPagedKVCache):
         kq, ksc = quantize_kv(kb)
         vq, vsc = quantize_kv(vb)
@@ -243,14 +245,14 @@ def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
     """Paged decode attention for one layer: write the new KV row into the
     block-table-addressed pool slot, then attend over live blocks only.
 
-    q/k_new/v_new: (B, 1, H|K, D); pool_k/pool_v: (N, bs, K, D) this
+    q/k_new/v_new: (B, 1, H|K, D); pool_k/pool_v: (N, K, bs, D) this
     layer's slice of the global pool; block_tables: (B, max_blocks);
     length: (B,) rows already valid (the new row is written at ``length``).
     Retired slots have all-zero tables, so their writes land in the trash
     block and never corrupt blocks reused by live requests.
     """
     from repro.kernels.decode_attention.ops import paged_decode_attention
-    N, bs, K, D = pool_k.shape
+    N, K, bs, D = pool_k.shape
     B = q.shape[0]
     mb = block_tables.shape[1]
     bi = jnp.clip(length // bs, 0, mb - 1)
@@ -261,17 +263,17 @@ def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
         k_scale, v_scale = scales
         kq, ks = quantize_kv(row_k)
         vq, vs = quantize_kv(row_v)
-        nk = pool_k.at[bt, off].set(kq)
-        nv = pool_v.at[bt, off].set(vq)
-        nks = k_scale.at[bt, off].set(ks)
-        nvs = v_scale.at[bt, off].set(vs)
+        nk = pool_k.at[bt, :, off].set(kq)
+        nv = pool_v.at[bt, :, off].set(vq)
+        nks = k_scale.at[bt, :, off].set(ks)
+        nvs = v_scale.at[bt, :, off].set(vs)
         out = paged_decode_attention(
             q[:, 0], nk, nv, block_tables, length + 1,
             k_scale=nks, v_scale=nvs, softcap=cfg.attn_logit_softcap,
             chunk=chunk)
         return out[:, None], (nk, nv, nks, nvs)
-    nk = pool_k.at[bt, off].set(row_k.astype(pool_k.dtype))
-    nv = pool_v.at[bt, off].set(row_v.astype(pool_v.dtype))
+    nk = pool_k.at[bt, :, off].set(row_k.astype(pool_k.dtype))
+    nv = pool_v.at[bt, :, off].set(row_v.astype(pool_v.dtype))
     out = paged_decode_attention(q[:, 0], nk, nv, block_tables, length + 1,
                                  softcap=cfg.attn_logit_softcap, chunk=chunk)
     return out[:, None], (nk, nv)
@@ -300,7 +302,7 @@ def _paged_prefill_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
     their rows (and any duplicate trash hits) are harmless garbage.
     """
     from repro.kernels.prefill_attention.ops import paged_prefill_attention
-    N, bs, K, D = pool_k.shape
+    N, K, bs, D = pool_k.shape
     C = q.shape[1]
     if write_ids is None:
         B = q.shape[0]
@@ -313,22 +315,22 @@ def _paged_prefill_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
             k_scale, v_scale = scales
             kq, ksc = quantize_kv(k_new)
             vq, vsc = quantize_kv(v_new)
-            nk = pool_k.at[bt, off].set(kq)
-            nv = pool_v.at[bt, off].set(vq)
-            nks = k_scale.at[bt, off].set(ksc)
-            nvs = v_scale.at[bt, off].set(vsc)
+            nk = pool_k.at[bt, :, off].set(kq)
+            nv = pool_v.at[bt, :, off].set(vq)
+            nks = k_scale.at[bt, :, off].set(ksc)
+            nvs = v_scale.at[bt, :, off].set(vsc)
             out = paged_prefill_attention(
                 q, nk, nv, table, q_start, kv_len, k_scale=nks, v_scale=nvs,
                 softcap=cfg.attn_logit_softcap, chunk=chunk)
             return out, (nk, nv, nks, nvs)
-        nk = pool_k.at[bt, off].set(k_new.astype(pool_k.dtype))
-        nv = pool_v.at[bt, off].set(v_new.astype(pool_v.dtype))
+        nk = pool_k.at[bt, :, off].set(k_new.astype(pool_k.dtype))
+        nv = pool_v.at[bt, :, off].set(v_new.astype(pool_v.dtype))
         out = paged_prefill_attention(q, nk, nv, table, q_start, kv_len,
                                       softcap=cfg.attn_logit_softcap,
                                       chunk=chunk)
         return out, (nk, nv)
-    kb = k_new[0].reshape(C // bs, bs, K, D)
-    vb = v_new[0].reshape(C // bs, bs, K, D)
+    kb = k_new[0].reshape(C // bs, bs, K, D).swapaxes(1, 2)
+    vb = v_new[0].reshape(C // bs, bs, K, D).swapaxes(1, 2)
     if scales is not None:
         k_scale, v_scale = scales
         kq, ksc = quantize_kv(kb)
@@ -358,7 +360,7 @@ def block_apply(cfg, p, x, positions, *,
     Without cache: full self-attention over x (train / prefill).
     With cache (decode): x is (B, 1, D); the new KV row is written at
     ``kv_len`` and attention runs over the whole cache.  With
-    ``block_tables`` the cache is paged: cache_k/v are (N, bs, K, D) pool
+    ``block_tables`` the cache is paged: cache_k/v are (N, K, bs, D) pool
     slices and reads gather only live blocks.  ``paged_prefill`` (a dict
     of write_ids/table/q_start/kv_len) switches the paged path to the
     multi-row chunk prefill: KV written straight into pool blocks,

@@ -72,7 +72,20 @@ QUADRO_K4000 = ChipSpec(
     tdp_watts=80.0,
 )
 
-CHIPS = {c.name: c for c in (TPU_V5E, MYRIAD2_VPU, XEON_E5_2609V2, QUADRO_K4000)}
+# TPU chips by ``device_kind`` as JAX reports it (a v5e reports "TPU v5 lite").
+TPU_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for(device) -> ChipSpec | None:
+    """The spec of a JAX device, or None when it is not a TPU.  A TPU kind
+    missing from :data:`TPU_KINDS` is an error, never a default."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return TPU_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(f"unknown TPU device_kind {device.device_kind!r}; "
+                         f"add its spec to TPU_KINDS") from None
 
 
 def bisection_bandwidth(chip: ChipSpec, num_chips: int) -> float:

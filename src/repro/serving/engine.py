@@ -49,6 +49,7 @@ in `benchmarks/serving_bench.py`.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 import time
@@ -58,6 +59,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.offload import WorkError
 from repro.models.registry import fns_for
@@ -337,6 +339,19 @@ def prefix_digests(tokens: np.ndarray, block_size: int) -> list[bytes]:
     return keys
 
 
+def _place(tree, device):
+    """Commit a pytree to ``device`` (None leaves it where it is)."""
+    return tree if device is None else jax.device_put(tree, device)
+
+
+def _build_on(device, make):
+    """Build a pytree of arrays directly on ``device`` (None: JAX's default
+    device), never staging it on another chip first."""
+    if device is None:
+        return make()
+    return jax.jit(make, out_shardings=SingleDeviceSharding(device))()
+
+
 def _merge_slot(state, slot_state, slot: jax.Array):
     """Write a single-request decode state into slot ``slot`` of the batched
     state.  Both pytrees come from the same model fns with the same
@@ -408,9 +423,11 @@ class _Drafter:
     """
 
     def __init__(self, cfg, params, *, slots: int, max_len: int,
-                 block_size: int, spec_k: int, chunk: int, cache_dtype: str):
+                 block_size: int, spec_k: int, chunk: int, cache_dtype: str,
+                 device=None):
         self.cfg = cfg
-        self.params = params
+        self.device = device
+        self.params = _place(params, device)
         self.fns = fns_for(cfg)
         if self.fns.init_paged_state is None or self.fns.prefill_paged is None:
             raise ValueError(f"draft family {cfg.family!r} has no paged-KV "
@@ -423,9 +440,9 @@ class _Drafter:
         self._tables = np.zeros((slots, self.max_blocks), np.int32)
         self._lens = np.zeros((slots,), np.int32)
         self._blocks: dict[int, list[int]] = {}
-        self._state = self.fns.init_paged_state(
+        self._state = _build_on(device, lambda: self.fns.init_paged_state(
             cfg, self.pool.total_blocks, block_size, slots, self.max_blocks,
-            cache_dtype)
+            cache_dtype))
         self._decode = jax.jit(
             lambda p, t, s: self.fns.decode(cfg, p, t, s, chunk=chunk))
         self._prefill = jax.jit(
@@ -463,10 +480,10 @@ class _Drafter:
         mb_eff = min(mb_eff, self.max_blocks)
         tbl = np.zeros((1, mb_eff), np.int32)
         tbl[0, :min(nbp, mb_eff)] = ids[:min(nbp, mb_eff)]
+        put = functools.partial(jax.device_put, device=self.device)
         _, self._state = self._prefill(
-            self.params, jnp.asarray(toks), self._state,
-            jnp.asarray(wids), jnp.asarray(tbl),
-            jnp.asarray([0], jnp.int32), jnp.asarray([P], jnp.int32),
+            self.params, put(toks), self._state, put(wids), put(tbl),
+            put(np.asarray([0], np.int32)), put(np.asarray([P], np.int32)),
             jnp.int32(P - 1))
         self._lens[slot] = P
 
@@ -520,9 +537,11 @@ class _Drafter:
                 write_pos[slot] += 1
                 live.append(slot)
             self._state = self._state._replace(
-                block_tables=jnp.asarray(tbl), length=jnp.asarray(lens))
+                block_tables=jax.device_put(tbl, self.device),
+                length=jax.device_put(lens, self.device))
             last, self._state = self._decode(
-                self.params, jnp.asarray(feed)[:, None], self._state)
+                self.params, jax.device_put(feed[:, None], self.device),
+                self._state)
             last = np.asarray(last)
             for slot in live:
                 if not queues[slot] and len(drafts[slot]) < k:
@@ -553,9 +572,13 @@ class ServingEngine:
                  draft_cfg=None, draft_params=None, spec_k: int = 3,
                  name: str = "", fault_plan: FaultPlan | None = None,
                  shed_queue_depth: int | None = None,
-                 role: str = "mixed"):
+                 role: str = "mixed", device=None):
         self.cfg = cfg
-        self.params = params
+        # the replica's device: params and KV state are committed to it, so
+        # every jitted step runs there whatever thread dispatches it
+        # (None = JAX's default device, uncommitted)
+        self.device = device
+        self.params = _place(params, device)
         # fault tolerance: the replica's name (fault-plan replica filter +
         # router health identity), the injection plan, and the admission
         # shed threshold (queue depth beyond which submit() refuses with
@@ -711,9 +734,11 @@ class ServingEngine:
             self._kv_io = None
         if spec:
             self._drafter = _Drafter(
-                draft_cfg, draft_params, slots=batch_slots, max_len=max_len,
-                block_size=block_size, spec_k=spec_k, chunk=chunk,
-                cache_dtype=cache_dtype)
+                draft_cfg,
+                self.params if draft_params is params else draft_params,
+                slots=batch_slots, max_len=max_len, block_size=block_size,
+                spec_k=spec_k, chunk=chunk, cache_dtype=cache_dtype,
+                device=device)
             self._verify = jax.jit(
                 lambda p, t, s, tb, qs, kl: self.fns.verify_paged(
                     cfg, p, t, s, tb, q_start=qs, kv_len=kl, chunk=chunk))
@@ -908,7 +933,7 @@ class ServingEngine:
     def _batch_for(self, prompts: np.ndarray) -> dict:
         """prompts: (W, S) -> model batch dict (positions/frames as needed)."""
         W, S = prompts.shape
-        batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
+        batch = {"tokens": self._put(np.asarray(prompts, np.int32))}
         if self.cfg.m_rope:
             pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (W, S))
             batch["positions"] = jnp.broadcast_to(pos[None], (3, W, S))
@@ -939,13 +964,18 @@ class ServingEngine:
         last, state = self._prefill(self.params, batch)
         return np.asarray(last[0]), state
 
+    def _put(self, x):
+        """Host array -> this replica's device."""
+        return jax.device_put(x, self.device)
+
     def _init_state(self):
         """Batched decode-state template covering all slots."""
         if self.paged:
-            return self.fns.init_paged_state(
+            return _build_on(self.device, lambda: self.fns.init_paged_state(
                 self.cfg, self.pool.total_blocks, self.block_size,
-                self.slots, self.max_blocks, self.cache_dtype)
-        return self.fns.init_decode_state(self.cfg, self.slots, self.max_len)
+                self.slots, self.max_blocks, self.cache_dtype))
+        return _build_on(self.device, lambda: self.fns.init_decode_state(
+            self.cfg, self.slots, self.max_len))
 
     # -- executor step ---------------------------------------------------------
 
@@ -1056,7 +1086,7 @@ class ServingEngine:
         for name, host in payload.items():
             arr = getattr(self._state, name)
             repl[name] = arr.at[:, bid].set(
-                jnp.asarray(host).astype(arr.dtype))
+                self._put(np.asarray(host)).astype(arr.dtype))
         self._state = self._state._replace(**repl)
 
     def _write_blocks(self, bids: list[int], payloads: list[dict]) -> None:
@@ -1080,14 +1110,14 @@ class ServingEngine:
             cap <<= 1
         bids = bids + [bids[-1]] * (cap - n)
         payloads = payloads + [payloads[-1]] * (cap - n)
-        idx = jnp.asarray(bids, dtype=jnp.int32)
+        idx = self._put(np.asarray(bids, np.int32))
         repl = {}
         for name in payloads[0]:
             arr = getattr(self._state, name)
             stacked = np.stack([np.asarray(p[name]) for p in payloads],
                                axis=1)
             repl[name] = arr.at[:, idx].set(
-                jnp.asarray(stacked).astype(arr.dtype))
+                self._put(stacked).astype(arr.dtype))
         self._state = self._state._replace(**repl)
 
     def _spill_block(self, bid: int, key: bytes) -> bool:
@@ -1398,10 +1428,10 @@ class ServingEngine:
         tbl[0, :nb_vis] = req.block_ids[:nb_vis]
         self._prefill_shapes.add((1, Cpad, mb_eff))
         last, self._state = self._prefill_paged(
-            self.params, jnp.asarray(chunk_toks), self._state,
-            jnp.asarray(wids), jnp.asarray(tbl),
-            jnp.asarray([start], jnp.int32),
-            jnp.asarray([start + real], jnp.int32),
+            self.params, self._put(chunk_toks), self._state,
+            self._put(wids), self._put(tbl),
+            self._put(np.asarray([start], np.int32)),
+            self._put(np.asarray([start + real], np.int32)),
             jnp.int32(real - 1))
         if self.role == "prefill":
             # full-budget chunks dispatch back-to-back, and on a shared
@@ -1595,8 +1625,8 @@ class ServingEngine:
                 self._tables[slot, nb] = req.block_ids[-1]
             self._lengths[slot] = pos
         self._state = self._state._replace(
-            block_tables=jnp.asarray(self._tables),
-            length=jnp.asarray(self._lengths))
+            block_tables=self._put(self._tables),
+            length=self._put(self._lengths))
 
     def _step(self) -> bool:
         """One executor iteration: refill free slots, spend the chunked
@@ -1756,7 +1786,7 @@ class ServingEngine:
             if self.paged:
                 self._grow_paged(still)
             last, self._state = self._decode(
-                self.params, jnp.asarray(feed)[:, None], self._state)
+                self.params, self._put(feed[:, None]), self._state)
             last = np.asarray(last)
             if self._spec_on:
                 # speculative slots fed 0 against trash tables: their rows
@@ -1854,8 +1884,8 @@ class ServingEngine:
             tbl[slot, :len(req.block_ids)] = req.block_ids
         self._prefill_shapes.add((self.slots, C, mb_eff))
         logits, self._state = self._verify(
-            self.params, jnp.asarray(tokens), self._state,
-            jnp.asarray(tbl), jnp.asarray(qs), jnp.asarray(kl))
+            self.params, self._put(tokens), self._state,
+            self._put(tbl), self._put(qs), self._put(kl))
         logits = np.asarray(logits)              # (slots, C, V)
         # 3. vectorized longest-prefix acceptance
         rows = np.array([s for s, _ in spec])
@@ -2188,7 +2218,8 @@ class ServingEngine:
                     if not active.any():
                         break
                     last, state = self._decode(
-                        self.params, jnp.asarray(toks, jnp.int32)[:, None],
+                        self.params,
+                        self._put(np.asarray(toks, np.int32)[:, None]),
                         state)
                     stats.decode_steps += 1
                     stats.occupancy_sum += active.sum() / self.slots

@@ -23,11 +23,6 @@ def run_with_devices(code: str, n: int = 8) -> dict:
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-seed failure (one of the 4 known multidev failures tracked in\n"
-           "ROADMAP, verified failing at seed commit 29cef53): the pinned jax\n"
-           "lacks jax.sharding.AxisType")
 def test_moe_ep_matches_dense():
     out = run_with_devices("""
         import json, jax, jax.numpy as jnp
@@ -56,11 +51,6 @@ def test_moe_ep_matches_dense():
     assert out["err"] < 1e-5
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-seed failure (one of the 4 known multidev failures tracked in\n"
-           "ROADMAP, verified failing at seed commit 29cef53): the pinned jax\n"
-           "lacks jax.sharding.AxisType")
 def test_lse_merge_decode_matches_local():
     out = run_with_devices("""
         import json, jax, jax.numpy as jnp
@@ -90,11 +80,6 @@ def test_lse_merge_decode_matches_local():
     assert out["out"] < 1e-5 and out["k"] == 0.0
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-seed failure (one of the 4 known multidev failures tracked in\n"
-           "ROADMAP, verified failing at seed commit 29cef53): the pinned jax\n"
-           "lacks jax.sharding.AxisType")
 def test_mini_dryrun_smoke_cell():
     """Lower+compile a smoke train step on an 8-device (2,4) mesh; verify
     memory analysis exists and collectives appear in the HLO."""
@@ -136,11 +121,6 @@ def test_mini_dryrun_smoke_cell():
     assert out["has_allreduce"]
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-seed failure (one of the 4 known multidev failures tracked in\n"
-           "ROADMAP, verified failing at seed commit 29cef53): the pinned jax\n"
-           "lacks jax.sharding.AxisType")
 def test_compressed_pod_mean_and_elastic():
     out = run_with_devices("""
         import json, jax, jax.numpy as jnp, numpy as np

@@ -186,8 +186,8 @@ def _ragged_case(seed, B=3, mb=4, bs=8, K=2, H=4, D=16):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     N = 1 + B * mb
     q = jax.random.normal(ks[0], (B, H, D))
-    k_pool = jax.random.normal(ks[1], (N, bs, K, D))
-    v_pool = jax.random.normal(ks[2], (N, bs, K, D))
+    k_pool = jax.random.normal(ks[1], (N, K, bs, D))
+    v_pool = jax.random.normal(ks[2], (N, K, bs, D))
     rng = np.random.default_rng(seed)
     tables = 1 + rng.permutation(B * mb).reshape(B, mb).astype(np.int32)
     lengths = rng.integers(1, mb * bs + 1, size=B).astype(np.int32)
@@ -197,9 +197,10 @@ def _ragged_case(seed, B=3, mb=4, bs=8, K=2, H=4, D=16):
 @pytest.mark.parametrize("seed", range(4))
 def test_paged_ref_matches_dense_ref_ragged(seed):
     q, kp, vp, tables, lengths = _ragged_case(seed)
-    B, mb, bs = q.shape[0], tables.shape[1], kp.shape[1]
-    kd = kp[tables].reshape(B, mb * bs, *kp.shape[2:])
-    vd = vp[tables].reshape(B, mb * bs, *vp.shape[2:])
+    B, mb = q.shape[0], tables.shape[1]
+    N, K, bs, D = kp.shape
+    kd = kp[tables].swapaxes(2, 3).reshape(B, mb * bs, K, D)
+    vd = vp[tables].swapaxes(2, 3).reshape(B, mb * bs, K, D)
     out = paged_decode_attention_ref(q, kp, vp, tables, lengths)
     ref = decode_attention_ref(q, kd, vd, lengths)
     np.testing.assert_allclose(out, ref, atol=1e-6)
@@ -242,8 +243,8 @@ def test_property_paged_matches_dense_over_ragged_lengths():
         N = 1 + B * mb
         ks = jax.random.split(jax.random.PRNGKey(seed % (2**31)), 3)
         q = jax.random.normal(ks[0], (B, H, D))
-        kp = jax.random.normal(ks[1], (N, bs, K, D))
-        vp = jax.random.normal(ks[2], (N, bs, K, D))
+        kp = jax.random.normal(ks[1], (N, K, bs, D))
+        vp = jax.random.normal(ks[2], (N, K, bs, D))
         tables = jnp.asarray(
             1 + rng.permutation(B * mb).reshape(B, mb).astype(np.int32))
         lengths = jnp.asarray(
@@ -255,15 +256,15 @@ def test_property_paged_matches_dense_over_ragged_lengths():
             scales = dict(k_scale=ksc, v_scale=vsc)
         out = paged_decode_attention_ref(q, kp, vp, tables, lengths,
                                          **scales)
-        kd = kp[tables].reshape(B, mb * bs, K, D)
-        vd = vp[tables].reshape(B, mb * bs, K, D)
+        kd = kp[tables].swapaxes(2, 3).reshape(B, mb * bs, K, D)
+        vd = vp[tables].swapaxes(2, 3).reshape(B, mb * bs, K, D)
         if quant:
             kd = (kd.astype(jnp.float32)
-                  * scales["k_scale"][tables].reshape(B, mb * bs, K)[
-                      ..., None]).astype(q.dtype)
+                  * scales["k_scale"][tables].swapaxes(2, 3).reshape(
+                      B, mb * bs, K)[..., None]).astype(q.dtype)
             vd = (vd.astype(jnp.float32)
-                  * scales["v_scale"][tables].reshape(B, mb * bs, K)[
-                      ..., None]).astype(q.dtype)
+                  * scales["v_scale"][tables].swapaxes(2, 3).reshape(
+                      B, mb * bs, K)[..., None]).astype(q.dtype)
         ref = decode_attention_ref(q, kd, vd, lengths)
         np.testing.assert_allclose(out, ref, atol=2e-6)
 

@@ -44,8 +44,8 @@ def _chunk_case(seed, B=2, C=8, mb=5, bs=8, K=2, H=4, D=16):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     N = 1 + B * mb
     q = jax.random.normal(ks[0], (B, C, H, D))
-    k_pool = jax.random.normal(ks[1], (N, bs, K, D))
-    v_pool = jax.random.normal(ks[2], (N, bs, K, D))
+    k_pool = jax.random.normal(ks[1], (N, K, bs, D))
+    v_pool = jax.random.normal(ks[2], (N, K, bs, D))
     rng = np.random.default_rng(seed)
     tables = 1 + rng.permutation(B * mb).reshape(B, mb).astype(np.int32)
     # chunk origin anywhere a block-aligned chunk fits (seeded rows before)
@@ -62,9 +62,10 @@ def test_prefill_ref_matches_dense_causal(seed):
     cache with query positions offset to the chunk origin."""
     q, kp, vp, tables, q_start, lengths = _chunk_case(seed)
     B, C = q.shape[:2]
-    mb, bs = tables.shape[1], kp.shape[1]
-    kd = kp[tables].reshape(B, mb * bs, *kp.shape[2:])
-    vd = vp[tables].reshape(B, mb * bs, *vp.shape[2:])
+    mb = tables.shape[1]
+    N, K, bs, D = kp.shape
+    kd = kp[tables].swapaxes(2, 3).reshape(B, mb * bs, K, D)
+    vd = vp[tables].swapaxes(2, 3).reshape(B, mb * bs, K, D)
     qpos = q_start[:, None] + jnp.arange(C)[None]
     dense = chunked_attention(q, kd, vd, causal=True, q_positions=qpos,
                               kv_positions=jnp.arange(mb * bs),
@@ -89,6 +90,27 @@ def test_prefill_pallas_int8_matches_ref():
                          k_scale=ks, v_scale=vs, interpret=True)
     ref = paged_prefill_attention_ref(q, kq, vq, tables, q_start, lengths,
                                       k_scale=ks, v_scale=vs)
+    np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_prefill_pallas_query_tiles_match_ref(monkeypatch, quant):
+    """A chunk whose rows span several query tiles (each tile masked
+    against its own absolute positions, and skipping blocks wholly after
+    its last query) equals the oracle."""
+    from repro.kernels.prefill_attention import kernel as PK
+    monkeypatch.setattr(PK, "_TILE_ROWS", 16)   # 8 positions x G=2 rows
+    q, kp, vp, tables, q_start, lengths = _chunk_case(11, C=32, mb=6)
+    assert PK._query_tile(32, 2) == 8
+    scales = {}
+    if quant:
+        kp, ks = T.quantize_kv(kp)
+        vp, vs = T.quantize_kv(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    out = pallas_prefill(q, kp, vp, tables, q_start, lengths, interpret=True,
+                         **scales)
+    ref = paged_prefill_attention_ref(q, kp, vp, tables, q_start, lengths,
+                                      **scales)
     np.testing.assert_allclose(out, ref, atol=2e-5)
 
 

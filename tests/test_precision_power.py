@@ -1,9 +1,12 @@
 """Precision error-delta estimators (paper §4.2) + power accounting (Eq.1)."""
+from typing import NamedTuple
+
 import numpy as np
 import pytest
 
-from repro.core.power import (PAPER_TDP_W, joules_per_item, report,
-                              throughput_per_watt, tpu_serving_report)
+from repro.core.power import (PAPER_TDP_W, joules_per_item, power_row,
+                              report, serving_power_report,
+                              throughput_per_watt)
 from repro.core.precision import (confidence_delta, prediction_agreement,
                                   top1_delta, top1_error_rate)
 
@@ -47,7 +50,41 @@ def test_power_eq1_paper_numbers():
     assert joules_per_item(77.2, 20.0) == pytest.approx(0.259, abs=1e-2)
 
 
+class _Device(NamedTuple):
+    """The three attributes the power model reads off a JAX device."""
+    id: int
+    platform: str
+    device_kind: str
+
+
 def test_tpu_serving_report():
-    r = tpu_serving_report(10_000.0, chips=256)
+    devs = [_Device(i, "tpu", "TPU v5 lite") for i in range(256)]
+    r = serving_power_report(10_000.0, devs)
+    assert r.device == "tpu-v5e" and r.n_devices == 256
     assert r.tdp_watts_total == 200.0 * 256
     assert r.items_per_watt == pytest.approx(10_000 / 51_200)
+
+
+def test_tpu_serving_report_counts_chips_not_replicas():
+    """Eight replicas on four chips are billed for four chips."""
+    devs = [_Device(i, "tpu", "TPU v5 lite") for i in range(4)]
+    r = serving_power_report(10_000.0, devs + devs)
+    assert r.n_devices == 4 and r.tdp_watts_total == 800.0
+
+
+def test_serving_power_not_measured_off_tpu():
+    r = serving_power_report(10.0, [_Device(0, "cpu", "cpu")])
+    assert r is None
+    assert "not measured" in power_row(r)
+
+
+def test_serving_power_unknown_tpu_kind_is_an_error():
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        serving_power_report(10.0, [_Device(0, "tpu", "TPU v99")])
+
+
+def test_report_unknown_device_is_an_error():
+    """A device without a TDP model is refused, never billed at another
+    chip's watts."""
+    with pytest.raises(KeyError):
+        report("tpu", 1, 10.0)
