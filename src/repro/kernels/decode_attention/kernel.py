@@ -226,5 +226,6 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
     return out.reshape(B, H, D)

@@ -171,6 +171,7 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, C * G, D), q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
       lengths.astype(jnp.int32), *args)
     return (out.reshape(B, K, C, G, D).transpose(0, 2, 1, 3, 4)

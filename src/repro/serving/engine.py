@@ -59,6 +59,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.offload import WorkError
@@ -120,6 +121,9 @@ MERGE_RULES: dict[str, str] = {
     "faults_injected": "sum",
     "kv_migrations": "sum",
     "migrated_blocks": "sum",
+    "step_host_s": "sum",
+    "queue_wait_s": "sum",
+    "admissions": "sum",
     "ttft": "extend",
     "tpot": "extend",
     "decode_gaps": "extend",
@@ -182,6 +186,11 @@ class ServeStats:
     faults_injected: int = 0            # fault-plan probes that fired here
     kv_migrations: int = 0              # disagg: prefills adopted from a peer
     migrated_blocks: int = 0            # disagg: pool blocks landed via adopt
+    step_host_s: float = 0.0            # executor step time not spent
+                                        # blocked on the device
+    queue_wait_s: float = 0.0           # sum of admitted_at - submitted_at
+                                        # over first admissions
+    admissions: int = 0                 # first admissions (resumes excluded)
     ttft: list = field(default_factory=list)    # per-request seconds
     tpot: list = field(default_factory=list)    # per-request seconds/token
     decode_gaps: list = field(default_factory=list)  # s between decode steps
@@ -316,6 +325,9 @@ class WindowBase(NamedTuple):
     faults_injected: int = 0
     kv_migrations: int = 0      # disagg lifetime counters (0 when mixed)
     migrated_blocks: int = 0
+    step_host_s: float = 0.0    # executor host-time and queue-wait counters
+    queue_wait_s: float = 0.0
+    admissions: int = 0
 
 
 def prefix_digests(tokens: np.ndarray, block_size: int) -> list[bytes]:
@@ -675,6 +687,7 @@ class ServingEngine:
         # feeds it forward without re-sampling or re-delivering it
         self._adopted_feed: dict[int, int] = {}  # owned-by: executor-thread
         self._last_decode_end: float | None = None  # owned-by: executor-thread
+        self._step_wait_s = 0.0  # owned-by: executor-thread; device waits this step
         self._gaps_dropped = 0  # owned-by: executor-thread; decode_gaps entries trimmed
         if paged and getattr(cfg, "sliding_window", 0):
             # the paged attention paths (prefill and decode) are
@@ -705,11 +718,14 @@ class ServingEngine:
                                  f"no paged prefill (ModelFns.prefill_paged"
                                  f" is None)")
             # cache-seeded chunked prefill: prompt KV written directly
-            # into pool blocks; one compile per padded chunk length
-            self._prefill_paged = jax.jit(
-                lambda p, t, s, w, tb, qs, kl, li: self.fns.prefill_paged(
+            # into pool blocks; one compile per padded chunk length.  The
+            # step programs are named functions, so the host trace and the
+            # device's program list say which one ran
+            def prefill_paged_step(p, t, s, w, tb, qs, kl, li):
+                return self.fns.prefill_paged(
                     cfg, p, t, s, w, tb, q_start=qs, kv_len=kl,
-                    last_idx=li, chunk=chunk))
+                    last_idx=li, chunk=chunk)
+            self._prefill_paged = jax.jit(prefill_paged_step)
         else:
             self.pool = None
         if self.tiered:
@@ -739,17 +755,21 @@ class ServingEngine:
                 slots=batch_slots, max_len=max_len, block_size=block_size,
                 spec_k=spec_k, chunk=chunk, cache_dtype=cache_dtype,
                 device=device)
-            self._verify = jax.jit(
-                lambda p, t, s, tb, qs, kl: self.fns.verify_paged(
-                    cfg, p, t, s, tb, q_start=qs, kv_len=kl, chunk=chunk))
+
+            def verify_step(p, t, s, tb, qs, kl):
+                return self.fns.verify_paged(cfg, p, t, s, tb, q_start=qs,
+                                             kv_len=kl, chunk=chunk)
+            self._verify = jax.jit(verify_step)
         else:
             self._drafter = None
         self._spec_on: set = set()  # owned-by: executor-thread; slots decoding speculatively
         self.scheduler = ContinuousScheduler(batch_slots, pool=self.pool,
                                              preemption=preemption,
                                              spec_rows=self.spec_rows)
-        self._decode = jax.jit(
-            lambda p, t, s: self.fns.decode(cfg, p, t, s, chunk=chunk))
+
+        def decode_step(p, t, s):
+            return self.fns.decode(cfg, p, t, s, chunk=chunk)
+        self._decode = jax.jit(decode_step)
         # jitted prefill, shape-keyed: one compile per (batch, prompt-len)
         # signature — used by the contiguous continuous path and the legacy
         # wave path (which needs a full worst-case ``max_len`` cache).
@@ -1379,104 +1399,109 @@ class ServingEngine:
         """
         job = self._prefilling[slot]
         req = job.req
-        if self._fault("engine.prefill", rid=req.rid) == "drop":
-            raise FaultError("engine.prefill",
-                             f"dropped prefill chunk of {req.rid}")
-        if job.pos == -1:
-            self._materialize_blocks(job)
-        if job.pos == -2:
-            # host-tier fetches still inbound: try a non-blocking drain,
-            # then skip this slot for the step (like a mid-prefill slot)
-            # rather than stall the batch on the transfer
-            self._drain_tier(timeout=0.0)
+        with TraceAnnotation("engine.prefill_chunk", rid=req.rid) as span:
+            if self._fault("engine.prefill", rid=req.rid) == "drop":
+                raise FaultError("engine.prefill",
+                                 f"dropped prefill chunk of {req.rid}")
+            if job.pos == -1:
+                self._materialize_blocks(job)
             if job.pos == -2:
-                return 0
-        P = len(job.tokens)
-        start = job.pos
-        remaining = P - start
-        bucket = self._bucket_len(remaining)
-        bs = self.block_size
-        cap = self.prefill_chunk
-        if cap is not None and budget is not None and budget < cap:
-            # spend only a power-of-two multiple of block_size of the
-            # leftover budget: an arbitrary block-multiple width would be
-            # a never-warmed jit signature compiling on the hot path
-            cap = bs
-            while cap * 2 <= budget:
-                cap *= 2
-        Cpad = min(cap, bucket) if cap else bucket
-        real = min(remaining, Cpad)
-        b0 = start // bs
-        chunk_toks = np.zeros((1, Cpad), np.int32)
-        chunk_toks[0, :real] = job.tokens[start:start + real]
-        wids = np.zeros((Cpad // bs,), np.int32)
-        for j in range(Cpad // bs):
-            lb = b0 + j                      # logical block of this write
-            if req.shared_blocks <= lb < job.nb:
-                wids[j] = req.block_ids[lb]
-        # read table sliced to the blocks this chunk can actually see
-        # (rounded up to a power of two): the attention gather scales
-        # with rows seeded-so-far, not the slot's worst-case table width,
-        # and the compile cache is keyed by (chunk, seeded) shape
-        mb_need = -(-(start + real) // bs)
-        mb_eff = 1
-        while mb_eff < mb_need:
-            mb_eff *= 2
-        mb_eff = min(mb_eff, self.max_blocks)
-        tbl = np.zeros((1, mb_eff), np.int32)
-        nb_vis = min(job.nb, mb_eff)
-        tbl[0, :nb_vis] = req.block_ids[:nb_vis]
-        self._prefill_shapes.add((1, Cpad, mb_eff))
-        last, self._state = self._prefill_paged(
-            self.params, self._put(chunk_toks), self._state,
-            self._put(wids), self._put(tbl),
-            self._put(np.asarray([start], np.int32)),
-            self._put(np.asarray([start + real], np.int32)),
-            jnp.int32(real - 1))
-        if self.role == "prefill":
-            # full-budget chunks dispatch back-to-back, and on a shared
-            # backend (co-located replicas in tests and benches) an
-            # unforced run piles tens of ms of queued compute that a
-            # decode replica's next op then waits behind — force each
-            # chunk so the convoy never forms.  A dedicated-device
-            # prefill replica loses nothing: its chunks are serially
-            # dependent through the KV state anyway.
-            jax.block_until_ready(last)
-        self.totals.prefill_tokens_computed += real
-        job.pos = start + real
-        if job.pos == P:                     # logits of the last real token
-            del self._prefilling[slot]
-            self._tables[slot] = 0
-            if self.role == "prefill" and self._on_prefilled is not None:
-                # disaggregated fleet: this replica's work ends at the
-                # last prompt token — hand the blocks to the router's
-                # migration channel instead of entering decode
-                self._handoff(slot, job, req, np.asarray(last[0]))
-                return real
-            if slot in self._spec_on:
-                # speculative slots never join the batched vanilla decode:
-                # their batched-state table row stays at trash (the decode
-                # step's write for this slot must keep landing nowhere) and
-                # the verify pass addresses the real blocks through its own
-                # per-step table argument.  Seed the drafter's mirror now —
-                # after a preemption resume ``job.tokens`` carries the
-                # folded committed output, so the drafter re-prefills the
-                # same history the target just did.
-                self._lengths[slot] = 0
-                self._drafter.seed(
-                    slot, job.tokens,
-                    len(req.prompt) + req.max_new_tokens + self.spec_k)
-            else:
-                self._tables[slot, :job.nb] = req.block_ids
-                self._lengths[slot] = P
-            self._set_last(slot, np.asarray(last[0]))
-            if self.prefix_sharing:
-                self._register_prefix(job.keys, req)
-            req.state = RequestState.DECODE
-            # a PREFILL slot just became DECODE — i.e. preemptible — so a
-            # queue head blocked on pool pressure is worth re-checking
-            self.scheduler.notify_capacity()
-        return real
+                # host-tier fetches still inbound: try a non-blocking
+                # drain, then skip this slot for the step (like a
+                # mid-prefill slot) rather than stall the batch on the
+                # transfer
+                self._drain_tier(timeout=0.0)
+                if job.pos == -2:
+                    return 0
+            P = len(job.tokens)
+            start = job.pos
+            remaining = P - start
+            bucket = self._bucket_len(remaining)
+            bs = self.block_size
+            cap = self.prefill_chunk
+            if cap is not None and budget is not None and budget < cap:
+                # spend only a power-of-two multiple of block_size of the
+                # leftover budget: an arbitrary block-multiple width would
+                # be a never-warmed jit signature compiling on the hot path
+                cap = bs
+                while cap * 2 <= budget:
+                    cap *= 2
+            Cpad = min(cap, bucket) if cap else bucket
+            real = min(remaining, Cpad)
+            b0 = start // bs
+            chunk_toks = np.zeros((1, Cpad), np.int32)
+            chunk_toks[0, :real] = job.tokens[start:start + real]
+            wids = np.zeros((Cpad // bs,), np.int32)
+            for j in range(Cpad // bs):
+                lb = b0 + j                  # logical block of this write
+                if req.shared_blocks <= lb < job.nb:
+                    wids[j] = req.block_ids[lb]
+            # read table sliced to the blocks this chunk can actually see
+            # (rounded up to a power of two): the attention gather scales
+            # with rows seeded-so-far, not the slot's worst-case table
+            # width, and the compile cache is keyed by (chunk, seeded) shape
+            mb_need = -(-(start + real) // bs)
+            mb_eff = 1
+            while mb_eff < mb_need:
+                mb_eff *= 2
+            mb_eff = min(mb_eff, self.max_blocks)
+            tbl = np.zeros((1, mb_eff), np.int32)
+            nb_vis = min(job.nb, mb_eff)
+            tbl[0, :nb_vis] = req.block_ids[:nb_vis]
+            self._prefill_shapes.add((1, Cpad, mb_eff))
+            span.set_metadata(rows=real, width=Cpad, table=mb_eff)
+            last, self._state = self._prefill_paged(
+                self.params, self._put(chunk_toks), self._state,
+                self._put(wids), self._put(tbl),
+                self._put(np.asarray([start], np.int32)),
+                self._put(np.asarray([start + real], np.int32)),
+                jnp.int32(real - 1))
+            if self.role == "prefill":
+                # full-budget chunks dispatch back-to-back, and on a
+                # shared backend (co-located replicas in tests and benches)
+                # an unforced run piles tens of ms of queued compute that a
+                # decode replica's next op then waits behind — force each
+                # chunk so the convoy never forms.  A dedicated-device
+                # prefill replica loses nothing: its chunks are serially
+                # dependent through the KV state anyway.
+                jax.block_until_ready(last)
+            self.totals.prefill_tokens_computed += real
+            job.pos = start + real
+            if job.pos == P:                 # logits of the last real token
+                del self._prefilling[slot]
+                self._tables[slot] = 0
+                if (self.role == "prefill"
+                        and self._on_prefilled is not None):
+                    # disaggregated fleet: this replica's work ends at the
+                    # last prompt token — hand the blocks to the router's
+                    # migration channel instead of entering decode
+                    self._handoff(slot, job, req, np.asarray(last[0]))
+                    return real
+                if slot in self._spec_on:
+                    # speculative slots never join the batched vanilla
+                    # decode: their batched-state table row stays at trash
+                    # (the decode step's write for this slot must keep
+                    # landing nowhere) and the verify pass addresses the
+                    # real blocks through its own per-step table argument.
+                    # Seed the drafter's mirror now — after a preemption
+                    # resume ``job.tokens`` carries the folded committed
+                    # output, so the drafter re-prefills the same history
+                    # the target just did.
+                    self._lengths[slot] = 0
+                    self._drafter.seed(
+                        slot, job.tokens,
+                        len(req.prompt) + req.max_new_tokens + self.spec_k)
+                else:
+                    self._tables[slot, :job.nb] = req.block_ids
+                    self._lengths[slot] = P
+                self._set_last(slot, self._to_host(last[0])[0])
+                if self.prefix_sharing:
+                    self._register_prefix(job.keys, req)
+                req.state = RequestState.DECODE
+                # a PREFILL slot just became DECODE — i.e. preemptible — so
+                # a queue head blocked on pool pressure is worth re-checking
+                self.scheduler.notify_capacity()
+            return real
 
     def _handoff(self, slot: int, job: _PrefillJob, req: Request,
                  last1: np.ndarray) -> None:
@@ -1628,15 +1653,46 @@ class ServingEngine:
             block_tables=self._put(self._tables),
             length=self._put(self._lengths))
 
+    def _to_host(self, out: jax.Array) -> tuple[np.ndarray, float]:
+        """Wait for a step program's output, then copy it to the host:
+        returns the host array and the ``perf_counter`` reading at which
+        the copy ended.  The wait (``engine.device_wait``) is the step's
+        device time and is kept out of ``step_host_s``; the copy
+        (``engine.logits_to_host``: the transfer plus the host-side
+        relayout) is host time."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.device_wait"):
+            jax.block_until_ready(out)
+        t1 = time.perf_counter()
+        self._step_wait_s += t1 - t0
+        with TraceAnnotation("engine.logits_to_host", bytes=out.nbytes):
+            host = np.asarray(out)
+        return host, time.perf_counter()
+
     def _step(self) -> bool:
         """One executor iteration: refill free slots, spend the chunked
         prefill budget, sample one token per decoding slot (vectorized),
         advance the batched decode step.  Returns False when there was no
-        work."""
-        # a raise here is a *replica* fault, not a request fault: it
-        # escapes _step, kills the executor, and exercises the crash
-        # capture path (_record_crash / failure / stop)
-        self._fault("replica.executor")
+        work.
+
+        The iteration runs under the ``engine.step`` span, each phase
+        under its own ``engine.*`` span (visible only while a profiler
+        trace is live), and its wall time less its device waits adds to
+        ``step_host_s``, on one ``perf_counter`` clock."""
+        t0 = time.perf_counter()
+        self._step_wait_s = 0.0
+        try:
+            with TraceAnnotation("engine.step") as span:
+                return self._step_phases(span)
+        finally:
+            self.totals.step_host_s += (time.perf_counter() - t0
+                                        - self._step_wait_s)
+
+    def _admit_work(self) -> int:
+        """The admission phase of a step: expire deadlines, admit queued
+        requests into free slots, retire preemption victims, and start
+        each admitted request's prefill (all of it when un-chunked).
+        Returns the number admitted."""
         self._sweep_deadlines()
         admitted = self.scheduler.admit()
         if self.paged:
@@ -1694,6 +1750,16 @@ class ServingEngine:
                 # one request's raising prefill fails that request, not
                 # the executor (crash faults escape one level up)
                 self._fail_slot(slot, req, e)
+        return len(admitted)
+
+    def _step_phases(self, span: TraceAnnotation) -> bool:
+        """The body of :meth:`_step`; ``span`` is its ``engine.step``."""
+        # a raise here is a *replica* fault, not a request fault: it
+        # escapes _step, kills the executor, and exercises the crash
+        # capture path (_record_crash / failure / stop)
+        self._fault("replica.executor")
+        with TraceAnnotation("engine.admit") as admit_span:
+            admit_span.set_metadata(admitted=self._admit_work())
 
         if self._prefilling:
             # chunked mode: spend at most prefill_chunk prompt tokens per
@@ -1722,6 +1788,8 @@ class ServingEngine:
                     self._fail_slot(job.slot, job.req, e)
 
         active = self.scheduler.decoding()
+        span.set_metadata(decoding=len(active),
+                          prefilling=len(self._prefilling))
         if not active:
             # no decodes to stall — a prefill-only period is not a decode
             # gap, so the cadence anchor resets either way
@@ -1744,50 +1812,60 @@ class ServingEngine:
         if not active:
             return True
 
-        toks = self._sample_active(
-            [(s, r) for s, r in active if s not in self._adopted_feed])
+        to_sample = [(s, r) for s, r in active
+                     if s not in self._adopted_feed]
+        with TraceAnnotation("engine.sample", rows=len(to_sample)):
+            toks = self._sample_active(to_sample)
         now = time.monotonic()
         feed = np.zeros((self.slots,), np.int32)
-        for slot, req in active:
-            pend = self._adopted_feed.pop(slot, None)
-            tok = toks[slot] if pend is None else pend
-            try:
-                if self._fault("engine.decode", rid=req.rid) == "drop":
-                    raise FaultError("engine.decode",
-                                     f"dropped decode commit of {req.rid}")
-            except Exception as e:  # noqa: BLE001 — poison isolation: the
-                # failed slot leaves `feed` at 0 against a trashed table,
-                # exactly like a retired speculative slot
-                self._fail_slot(slot, req, e)
-                continue
-            feed[slot] = tok
-            if pend is not None:
-                # adopted slot: this token was sampled and delivered at
-                # the prefill replica's handoff — feed it forward, but do
-                # not deliver it twice (it cannot be the request's final
-                # token either: single-token requests finish at handoff)
-                continue
-            if req.first_token_at is None:
-                req.first_token_at = now
-            req.output.append(tok)
-            self.totals.tokens += 1
-            if len(req.output) >= req.max_new_tokens:
-                req.state = RequestState.DONE
-                req.finished_at = time.monotonic()
-                self.scheduler.release(slot)   # returns blocks to the pool
-                if self.paged:
-                    self._retire_slot(slot)
-                if req.on_finish is not None:
-                    req.on_finish(req)
+        with TraceAnnotation("engine.commit") as commit_span:
+            finished = 0
+            for slot, req in active:
+                pend = self._adopted_feed.pop(slot, None)
+                tok = toks[slot] if pend is None else pend
+                try:
+                    if self._fault("engine.decode", rid=req.rid) == "drop":
+                        raise FaultError(
+                            "engine.decode",
+                            f"dropped decode commit of {req.rid}")
+                except Exception as e:  # noqa: BLE001 — poison isolation:
+                    # the failed slot leaves `feed` at 0 against a trashed
+                    # table, exactly like a retired speculative slot
+                    self._fail_slot(slot, req, e)
+                    continue
+                feed[slot] = tok
+                if pend is not None:
+                    # adopted slot: this token was sampled and delivered
+                    # at the prefill replica's handoff — feed it forward,
+                    # but do not deliver it twice (it cannot be the
+                    # request's final token either: single-token requests
+                    # finish at handoff)
+                    continue
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                req.output.append(tok)
+                self.totals.tokens += 1
+                if len(req.output) >= req.max_new_tokens:
+                    req.state = RequestState.DONE
+                    req.finished_at = time.monotonic()
+                    self.scheduler.release(slot)   # blocks back to the pool
+                    if self.paged:
+                        self._retire_slot(slot)
+                    finished += 1
+                    if req.on_finish is not None:
+                        req.on_finish(req)
+            commit_span.set_metadata(finished=finished)
 
         still = [(s, r) for s, r in self.scheduler.decoding()
                  if s not in self._spec_on]
         if still:        # someone needs next-token logits
             if self.paged:
-                self._grow_paged(still)
-            last, self._state = self._decode(
-                self.params, self._put(feed[:, None]), self._state)
-            last = np.asarray(last)
+                with TraceAnnotation("engine.tables"):
+                    self._grow_paged(still)
+            with TraceAnnotation("engine.dispatch"):
+                last, self._state = self._decode(
+                    self.params, self._put(feed[:, None]), self._state)
+            last, t_read = self._to_host(last)
             if self._spec_on:
                 # speculative slots fed 0 against trash tables: their rows
                 # of this batched decode are garbage, and their real next-
@@ -1796,18 +1874,18 @@ class ServingEngine:
                 last = last.copy()
                 last[keep] = self._last[keep]
             self._last = last
-            self._note_decode_cadence()
+            self._note_decode_cadence(t_read)
             self.totals.decode_steps += 1
             self.totals.occupancy_sum += len(still) / self.slots
         else:
             self._last_decode_end = None     # cadence broken, not stalled
         return True
 
-    def _note_decode_cadence(self) -> None:
+    def _note_decode_cadence(self, now: float) -> None:
         """Record the wall-clock gap since the previous decode-cadence step
         (vanilla decode or speculative verify) — chunked-prefill stalls
-        surface here as ``decode_gaps`` outliers."""
-        now = time.monotonic()
+        surface here as ``decode_gaps`` outliers.  ``now`` is a
+        ``perf_counter`` reading: the step's own, where it took one."""
         if self._last_decode_end is not None:
             gaps = self.totals.decode_gaps
             gaps.append(now - self._last_decode_end)
@@ -1936,7 +2014,7 @@ class ServingEngine:
                 # never fed back)
                 q0 = int(qs[slot])
                 self._drafter.set_len(slot, q0 + min(len(commit), k))
-        self._note_decode_cadence()
+        self._note_decode_cadence(time.perf_counter())
         self.totals.verify_steps += 1
         self.totals.occupancy_sum += len(spec) / self.slots
 
@@ -1972,7 +2050,10 @@ class ServingEngine:
             shed_rejections=self.totals.shed_rejections,
             faults_injected=self.totals.faults_injected,
             kv_migrations=self.totals.kv_migrations,
-            migrated_blocks=self.totals.migrated_blocks)
+            migrated_blocks=self.totals.migrated_blocks,
+            step_host_s=self.totals.step_host_s,
+            queue_wait_s=self.scheduler.queue_wait_s,
+            admissions=self.scheduler.admissions)
 
     def collect_window(self, base: "WindowBase", requests: list[Request],
                        wall_s: float) -> ServeStats:
@@ -2014,6 +2095,9 @@ class ServingEngine:
                                - base.kv_migrations)
         stats.migrated_blocks = (self.totals.migrated_blocks
                                  - base.migrated_blocks)
+        stats.step_host_s = self.totals.step_host_s - base.step_host_s
+        stats.queue_wait_s = self.scheduler.queue_wait_s - base.queue_wait_s
+        stats.admissions = self.scheduler.admissions - base.admissions
         if stats.prefix_lookups:
             stats.kv_hit_rate = ((stats.prefix_shared_blocks
                                   + stats.prefix_hits_host)
@@ -2063,9 +2147,10 @@ class ServingEngine:
     def _service_loop(self) -> None:
         try:
             while not self._stop.is_set():
-                if not self.scheduler.wait_for_work(timeout=0.02):
-                    continue
-                self._step()
+                with TraceAnnotation("engine.idle"):
+                    ready = self.scheduler.wait_for_work(timeout=0.02)
+                if ready:
+                    self._step()
         except Exception as e:  # noqa: BLE001 — crash capture: the
             # executor must not die silently; record the failure, fail
             # every in-flight request (freeing its KV blocks), and poison

@@ -25,6 +25,9 @@ their priority), then by arrival.  ``submit`` stamps ``submitted_at`` at
 actual submission (unless the caller already set it — the multi-replica
 reissue path pins arrival time on the original so clones inherit it), so
 TTFT always measures queueing + prefill, never pre-construction time.
+``admit`` stamps ``admitted_at`` at a request's first admission only (a
+preemption resume keeps it), and sums ``admitted_at - submitted_at`` over
+first admissions into the ``queue_wait_s`` / ``admissions`` counters.
 
 With a :class:`~repro.serving.kv_pool.KVBlockPool` attached, admission is
 *block-aware*: a request enters a slot only when the pool can reserve its
@@ -93,6 +96,7 @@ class Request:
     state: RequestState = RequestState.QUEUED
     output: list = field(default_factory=list)
     submitted_at: float | None = None    # stamped by scheduler.submit()
+    admitted_at: float | None = None     # first admission (scheduler.admit)
     first_token_at: float | None = None
     finished_at: float | None = None
     on_finish: Callable[["Request"], None] | None = None
@@ -232,6 +236,8 @@ class ContinuousScheduler:
         self._preempted: list[tuple[int, Request]] = \
             []                               # guarded-by: self._lock
         self._preemptions = 0                # guarded-by: self._lock
+        self._admissions = 0                 # guarded-by: self._lock
+        self._queue_wait_s = 0.0             # guarded-by: self._lock
         # blocked-head admission cache: (head arrival_seq, capacity
         # version) of the last admit() that found the queue head unfit.
         # While the version is unchanged, re-running the slot scan /
@@ -254,6 +260,18 @@ class ContinuousScheduler:
     def preemptions(self) -> int:
         with self._lock:
             return self._preemptions
+
+    @property
+    def admissions(self) -> int:
+        """First admissions (preemption resumes not counted)."""
+        with self._lock:
+            return self._admissions
+
+    @property
+    def queue_wait_s(self) -> float:
+        """Sum of ``admitted_at - submitted_at`` over first admissions."""
+        with self._lock:
+            return self._queue_wait_s
 
     @property
     def head_checks_skipped(self) -> int:
@@ -370,6 +388,10 @@ class ContinuousScheduler:
                 if self.pool is not None:
                     req.blocks_reserved = need
                 heapq.heappop(self._heap)
+                if req.admitted_at is None:
+                    req.admitted_at = time.monotonic()
+                    self._admissions += 1
+                    self._queue_wait_s += req.admitted_at - req.submitted_at
                 req.state = RequestState.PREFILL
                 self.slots[slot] = req
                 out.append((slot, req))
