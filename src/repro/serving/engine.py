@@ -124,6 +124,8 @@ MERGE_RULES: dict[str, str] = {
     "step_host_s": "sum",
     "queue_wait_s": "sum",
     "admissions": "sum",
+    "decode_pages_live": "sum",
+    "decode_pages_table": "sum",
     "ttft": "extend",
     "tpot": "extend",
     "decode_gaps": "extend",
@@ -191,6 +193,10 @@ class ServeStats:
     queue_wait_s: float = 0.0           # sum of admitted_at - submitted_at
                                         # over first admissions
     admissions: int = 0                 # first admissions (resumes excluded)
+    decode_pages_live: int = 0          # paged: per decode step, pages the
+                                        # decode kernel reads, sum over slots
+                                        # of cdiv(length + 1, block size)
+    decode_pages_table: int = 0         # ... and slots x table width
     ttft: list = field(default_factory=list)    # per-request seconds
     tpot: list = field(default_factory=list)    # per-request seconds/token
     decode_gaps: list = field(default_factory=list)  # s between decode steps
@@ -328,6 +334,8 @@ class WindowBase(NamedTuple):
     step_host_s: float = 0.0    # executor host-time and queue-wait counters
     queue_wait_s: float = 0.0
     admissions: int = 0
+    decode_pages_live: int = 0  # decode kernel reads (paged)
+    decode_pages_table: int = 0
 
 
 def prefix_digests(tokens: np.ndarray, block_size: int) -> list[bytes]:
@@ -1639,7 +1647,9 @@ class ServingEngine:
     def _grow_paged(self, still: list[tuple[int, Request]]) -> None:
         """Allocate the next block for any request whose write position
         crossed a block boundary, then re-inject the host-side tables and
-        lengths into the decode state."""
+        lengths into the decode state.  Counts the pages the decode
+        kernel will read (every slot attends ``length + 1`` rows) against
+        the table's width."""
         bs = self.block_size
         for slot, req in still:
             pos = len(req.prompt) + len(req.output) - 1   # row written next
@@ -1649,6 +1659,8 @@ class ServingEngine:
                 req.blocks_reserved -= 1
                 self._tables[slot, nb] = req.block_ids[-1]
             self._lengths[slot] = pos
+        self.totals.decode_pages_live += int(np.sum(self._lengths // bs + 1))
+        self.totals.decode_pages_table += self._tables.size
         self._state = self._state._replace(
             block_tables=self._put(self._tables),
             length=self._put(self._lengths))
@@ -2053,7 +2065,9 @@ class ServingEngine:
             migrated_blocks=self.totals.migrated_blocks,
             step_host_s=self.totals.step_host_s,
             queue_wait_s=self.scheduler.queue_wait_s,
-            admissions=self.scheduler.admissions)
+            admissions=self.scheduler.admissions,
+            decode_pages_live=self.totals.decode_pages_live,
+            decode_pages_table=self.totals.decode_pages_table)
 
     def collect_window(self, base: "WindowBase", requests: list[Request],
                        wall_s: float) -> ServeStats:
@@ -2098,6 +2112,10 @@ class ServingEngine:
         stats.step_host_s = self.totals.step_host_s - base.step_host_s
         stats.queue_wait_s = self.scheduler.queue_wait_s - base.queue_wait_s
         stats.admissions = self.scheduler.admissions - base.admissions
+        stats.decode_pages_live = (self.totals.decode_pages_live
+                                   - base.decode_pages_live)
+        stats.decode_pages_table = (self.totals.decode_pages_table
+                                    - base.decode_pages_table)
         if stats.prefix_lookups:
             stats.kv_hit_rate = ((stats.prefix_shared_blocks
                                   + stats.prefix_hits_host)

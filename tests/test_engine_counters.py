@@ -1,5 +1,6 @@
-"""The executor's host-time and queue-wait counters: ``step_host_s``,
-``queue_wait_s`` and ``admissions`` in ``ServeStats``, the scheduler's
+"""The executor's host-time, queue-wait and decode-page counters:
+``step_host_s``, ``queue_wait_s``, ``admissions``, ``decode_pages_live``
+and ``decode_pages_table`` in ``ServeStats``, the scheduler's
 ``admitted_at`` stamp, their window scoping and their fleet merge."""
 import threading
 import time
@@ -107,3 +108,30 @@ def test_merge_sums_the_counters():
     a = ServeStats(step_host_s=1.25, queue_wait_s=2.0, admissions=3)
     a.merge_from(ServeStats(step_host_s=0.5, queue_wait_s=1.5, admissions=2))
     assert (a.step_host_s, a.queue_wait_s, a.admissions) == (1.75, 3.5, 5)
+
+
+def _pages_read(reqs, bs):
+    """Pages the decode kernel reads for these requests on one slot: the
+    step that yields output j >= 1 attends prompt + j rows."""
+    return sum((len(r.prompt) + j - 1) // bs + 1
+               for r in reqs for j in range(1, r.max_new_tokens))
+
+
+def test_decode_pages_follow_the_slots_lengths(smoke):
+    """One slot, requests in turn: every decode step reads cdiv(rows, bs)
+    pages of a max_blocks-wide table; two windows add up to the engine's
+    lifetime totals, and their stats merge by sum."""
+    cfg, params = smoke
+    eng = _engine(cfg, params)
+    first_reqs = _reqs(cfg, [0, 1], n_new=5, size=6)
+    second_reqs = _reqs(cfg, [2], n_new=7, size=9)
+    first, second = eng.serve(first_reqs), eng.serve(second_reqs)
+    for stats, reqs in ((first, first_reqs), (second, second_reqs)):
+        assert stats.decode_steps == sum(r.max_new_tokens - 1 for r in reqs)
+        assert stats.decode_pages_live == _pages_read(reqs, eng.block_size)
+        assert stats.decode_pages_table == (stats.decode_steps
+                                            * eng.max_blocks)
+    merged = ServeStats().merge_from(first).merge_from(second)
+    assert (merged.decode_pages_live, merged.decode_pages_table) == (
+        eng.totals.decode_pages_live, eng.totals.decode_pages_table)
+    assert 0 < merged.decode_pages_live < merged.decode_pages_table

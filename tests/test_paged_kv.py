@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import registry as R
+from repro.kernels.decode_attention.kernel import _pages_per_group
 from repro.kernels.decode_attention.kernel import \
     paged_decode_attention as pallas_paged
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
@@ -206,12 +207,84 @@ def test_paged_ref_matches_dense_ref_ragged(seed):
     np.testing.assert_allclose(out, ref, atol=1e-6)
 
 
-@pytest.mark.parametrize("seed", range(2))
-def test_paged_pallas_matches_ref_ragged(seed):
-    q, kp, vp, tables, lengths = _ragged_case(seed)
-    out = pallas_paged(q, kp, vp, tables, lengths, interpret=True)
-    ref = paged_decode_attention_ref(q, kp, vp, tables, lengths)
+def _group_case(K, G, cache_dtype, bs=16, D=128, seed=0):
+    """Slots at the lengths where the kernel's page groups change: 1, bs,
+    P*bs - 1, P*bs, P*bs + 1 and the whole table, plus retired slots (all
+    zero tables) of length 0 and 1.  Tables are shuffled pool pages, out of
+    order and not contiguous; P is what the kernel picks for these shapes.
+    Returns (q, pools, tables, lengths, scale kwargs)."""
+    itemsize = jnp.dtype(cache_dtype).itemsize
+    P = _pages_per_group(K, bs, D, itemsize, 1 << 20)
+    mb = 2 * P + 3
+    lengths = np.array([1, bs, P * bs - 1, P * bs, P * bs + 1, mb * bs,
+                        0, 1], np.int32)
+    B, live = len(lengths), 6
+    N = 1 + 2 * live * mb                    # half the pool is never used
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, K * G, D))
+    kp = jax.random.normal(ks[1], (N, K, bs, D))
+    vp = jax.random.normal(ks[2], (N, K, bs, D))
+    rng = np.random.default_rng(seed)
+    tables = np.zeros((B, mb), np.int32)
+    tables[:live] = (1 + rng.permutation(N - 1)[:live * mb]).reshape(live, mb)
+    if cache_dtype == "int8":
+        kp, k_scale = T.quantize_kv(kp)
+        vp, v_scale = T.quantize_kv(vp)
+        scales = dict(k_scale=k_scale, v_scale=v_scale)
+    else:
+        kp, vp = kp.astype(cache_dtype), vp.astype(cache_dtype)
+        scales = {}
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths), scales
+
+
+_HEADS = {"qwen2.5-3b": (2, 8), "qwen3-32b-8l": (8, 8)}
+
+
+def _pallas_cases():
+    yield from (pytest.param(None, None, seed, 0.0, id=str(seed))
+                for seed in range(2))
+    for heads in _HEADS:
+        for dtype in ("bfloat16", "int8"):
+            for softcap in (0.0, 30.0):
+                yield pytest.param(heads, dtype, 0, softcap,
+                                   id=f"{heads}-{dtype}-softcap{softcap:g}")
+
+
+@pytest.mark.parametrize("heads,cache_dtype,seed,softcap", _pallas_cases())
+def test_paged_pallas_matches_ref_ragged(heads, cache_dtype, seed, softcap):
+    if heads is None:
+        q, kp, vp, tables, lengths = _ragged_case(seed)
+        scales = {}
+    else:
+        q, kp, vp, tables, lengths, scales = _group_case(
+            *_HEADS[heads], cache_dtype, seed=seed)
+    out = pallas_paged(q, kp, vp, tables, lengths, softcap=softcap,
+                       interpret=True, **scales)
+    ref = paged_decode_attention_ref(q, kp, vp, tables, lengths,
+                                     softcap=softcap, **scales)
     np.testing.assert_allclose(out, ref, atol=2e-5)
+
+
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+def test_paged_pallas_reads_no_page_past_the_length(cache_dtype):
+    """Every pool page outside the slots' live prefixes (the trash page,
+    the tails of the tables, the pages no table names) is NaN (for int8,
+    its scales): the output stays finite and equal to the clean pool's."""
+    q, kp, vp, tables, lengths, scales = _group_case(2, 8, cache_dtype)
+    bs = kp.shape[2]
+    live = np.zeros(kp.shape[0], bool)
+    for row, n in zip(np.asarray(tables), np.asarray(lengths)):
+        live[row[:-(-n // bs)]] = True
+    dead = jnp.asarray(~live)[:, None, None]
+    clean = pallas_paged(q, kp, vp, tables, lengths, interpret=True,
+                         **scales)
+    if scales:
+        scales = {k: jnp.where(dead, jnp.nan, v) for k, v in scales.items()}
+    else:
+        kp, vp = (jnp.where(dead[..., None], jnp.nan, x) for x in (kp, vp))
+    out = pallas_paged(q, kp, vp, tables, lengths, interpret=True, **scales)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_array_equal(out, clean)
 
 
 def test_paged_pallas_int8_matches_ref():

@@ -61,9 +61,9 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _pool(sharding, quant: bool):
+def _pool(sharding, quant: bool, cfg=CFG, N=1 + SLOTS * MB):
     """k/v pools (N, K, bs, D) and, for int8, their (N, K, bs) scales."""
-    N, K, D = 1 + SLOTS * MB, CFG.num_kv_heads, CFG.resolved_head_dim
+    K, D = cfg.num_kv_heads, cfg.resolved_head_dim
     dt = jnp.int8 if quant else jnp.bfloat16
     kv = [_sds(sharding, (N, K, BS, D), dt)] * 2
     sc = [_sds(sharding, (N, K, BS), jnp.float32)] * 2 if quant else []
@@ -75,14 +75,28 @@ def _assert_kernel(compiled):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
-def test_paged_decode_compiles_for_v5e(one_chip, cache_dtype):
-    quant = cache_dtype == "int8"
-    (kp, vp), sc = _pool(one_chip, quant)
-    q = _sds(one_chip, (SLOTS, CFG.num_heads, CFG.resolved_head_dim),
+# The decode kernel at the reasoning cell's serving shapes (32 slots, a
+# 288-page table for max_len 4,608), for both head layouts it serves:
+# qwen2.5-3b (K = 2, G = 8) and the qwen3-32b stage (K = 8, G = 8).
+DECODE_SLOTS, DECODE_MB = 32, 288
+
+
+def _decode_cases():
+    for arch in ("qwen2.5-3b", "qwen3-32b"):
+        for dtype in ("bfloat16", "int8"):
+            yield pytest.param(arch, dtype, id=dtype if arch == CFG.name
+                               else f"{arch}-{dtype}")
+
+
+@pytest.mark.parametrize("arch,cache_dtype", _decode_cases())
+def test_paged_decode_compiles_for_v5e(one_chip, arch, cache_dtype):
+    cfg = R.config(arch)
+    (kp, vp), sc = _pool(one_chip, cache_dtype == "int8", cfg,
+                         N=1 + DECODE_SLOTS * DECODE_MB)
+    q = _sds(one_chip, (DECODE_SLOTS, cfg.num_heads, cfg.resolved_head_dim),
              jnp.bfloat16)
-    tables = _sds(one_chip, (SLOTS, MB), jnp.int32)
-    lengths = _sds(one_chip, (SLOTS,), jnp.int32)
+    tables = _sds(one_chip, (DECODE_SLOTS, DECODE_MB), jnp.int32)
+    lengths = _sds(one_chip, (DECODE_SLOTS,), jnp.int32)
 
     def step(q, kp, vp, tables, lengths, *scales):
         kw = dict(k_scale=scales[0], v_scale=scales[1]) if scales else {}
