@@ -1,8 +1,9 @@
 """Everything the benchmark touches of the system under test, in one place.
 
-The benchmark builds the served model from its own configuration file and
-its own seeded weights, and hands them to the launcher's own fleet
-builder (``repro.launch.serve.build``); it warms up the engine's jitted
+The benchmark builds the served model from its own configuration file (the
+program's ``ModelConfig`` from its architecture module) and its own seeded
+weights, and hands them to the launcher's own fleet builder
+(``repro.launch.serve.build``); it warms up the engine's jitted
 step programs at every shape the cell's traffic can reach.  Nothing else
 of the program is used: the window drives ``ServingEngine.start`` /
 ``submit``, and the results are the requests' own outputs and stamps and
@@ -12,19 +13,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench import archs
 from bench import weights as W
-
-
-def model_config(conf: dict):
-    from repro.configs.base import ModelConfig
-    s = W.spec_of(conf)
-    return ModelConfig(
-        name=conf["name"], family="dense", num_layers=s["L"],
-        d_model=s["d"], num_heads=s["H"], num_kv_heads=s["K"], d_ff=s["f"],
-        vocab_size=s["V"], head_dim=s["hd"], qkv_bias=s["qkv_bias"],
-        qk_norm=s["qk_norm"], rope_theta=s["theta"],
-        tie_embeddings=s["tie"], norm_eps=s["eps"],
-        param_dtype=s["dtype"], compute_dtype=s["dtype"])
 
 
 def init_params(conf: dict, cfg, seed: int, device):
@@ -34,9 +24,10 @@ def init_params(conf: dict, cfg, seed: int, device):
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
     from repro.models.registry import fns_for
-    s = W.spec_of(conf)
+    arch = archs.of(conf)
+    s = arch.spec_of(conf)
     dt = jnp.dtype(s["dtype"])
-    make = jax.jit(lambda key: W.make_tree(s, key, dt),
+    make = jax.jit(lambda key: W.make_tree(arch, s, key, dt),
                    out_shardings=SingleDeviceSharding(device))
     want = jax.eval_shape(lambda k: fns_for(cfg).init(cfg, k),
                           jax.random.PRNGKey(0))

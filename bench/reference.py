@@ -1,17 +1,11 @@
-"""The plain reference: a Qwen2/Qwen3 dense decoder in float32 ``jax.numpy``,
-written from the published equations and importing nothing of the
-program.  It makes its weights again from the seed (``bench/weights.py``)
-and upcasts the served bf16 values, so the only difference from the
-served model is arithmetic: float32 at ``Precision.HIGHEST`` everywhere,
-no cache, no kernel, no chunking, no batching across requests.
-
-Per layer (pre-norm, as ``Qwen2DecoderLayer``/``Qwen3DecoderLayer``):
-    h = RMSNorm(x); q,k,v = h Wq (+bq), h Wk (+bk), h Wv (+bv)
-    [Qwen3] q,k = RMSNorm_head(q), RMSNorm_head(k)
-    q,k = RoPE(q), RoPE(k)  (rotate-half form, theta from the config)
-    x = x + softmax(q k^T / sqrt(hd) + causal) v Wo
-    x = x + W_down(silu(h2 W_gate) * h2 W_up),  h2 = RMSNorm(x)
-logits = RMSNorm(x) E^T (tied) or RMSNorm(x) W_head (untied).
+"""The plain reference: the configuration's model in float32
+``jax.numpy``, written from the published equations and importing nothing
+of the program.  Its equations come from the configuration's architecture
+module (``bench/archs/``); this file runs them.  It makes its weights again
+from the seed (``bench/weights.py``) and upcasts the served bf16 values, so
+the only difference from the served model is arithmetic: float32 at
+``Precision.HIGHEST`` everywhere, no cache, no kernel, no chunking, no
+batching across requests.
 
 It runs layer by layer over a block of whole sequences, after the
 served model has been freed, so it fits beside nothing else.  With
@@ -27,10 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import archs
 from bench import weights as W
+from bench.archs import HI, QBLOCK
 
-HI = jax.lax.Precision.HIGHEST
-QBLOCK = 512          # attention query rows per block
 HEAD_ROWS = 256       # LM-head rows per block
 FP8_MAX = 448.0       # largest finite float8_e4m3fn
 
@@ -50,90 +44,44 @@ def _mm(a, w, quant):
     return jnp.matmul(a, w, precision=HI)
 
 
-def _rms(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, pos, theta):
-    """x: (S, n, hd); rotate-half RoPE at absolute positions ``pos``."""
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd))
-    ang = pos[:, None].astype(jnp.float32) * jnp.asarray(inv)[None]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(s, quant, w, x):
-    """One decoder layer over one whole sequence x: (S, d) float32."""
-    S, d = x.shape
-    H, K, hd = s["H"], s["K"], s["hd"]
-    G = H // K
-    w = {k: v.astype(jnp.float32) for k, v in w.items()}
-    pos = jnp.arange(S, dtype=jnp.int32)
-    h = _rms(x, w["ln1.scale"], s["eps"])
-    q = _mm(h, w["attn.wq"].reshape(d, H * hd), quant).reshape(S, H, hd)
-    k = _mm(h, w["attn.wk"].reshape(d, K * hd), quant).reshape(S, K, hd)
-    v = _mm(h, w["attn.wv"].reshape(d, K * hd), quant).reshape(S, K, hd)
-    if s["qkv_bias"]:
-        q, k, v = q + w["attn.bq"], k + w["attn.bk"], v + w["attn.bv"]
-    if s["qk_norm"]:
-        q = _rms(q, w["attn.q_norm"], s["eps"])
-        k = _rms(k, w["attn.k_norm"], s["eps"])
-    q, k = _rope(q, pos, s["theta"]), _rope(k, pos, s["theta"])
-    qg = q.reshape(S // QBLOCK, QBLOCK, K, G, hd)
-
-    def attend(args):
-        qb, q0 = args                                   # (QB, K, G, hd)
-        sc = jnp.einsum("qkgd,skd->kgqs", qb, k, precision=HI) / np.sqrt(hd)
-        qpos = q0 + jnp.arange(QBLOCK)
-        sc = jnp.where(pos[None, :] <= qpos[:, None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("kgqs,skd->qkgd", p, v, precision=HI)
-
-    starts = jnp.arange(S // QBLOCK, dtype=jnp.int32) * QBLOCK
-    att = jax.lax.map(attend, (qg, starts)).reshape(S, H * hd)
-    x = x + _mm(att, w["attn.wo"].reshape(H * hd, d), quant)
-    h2 = _rms(x, w["ln2.scale"], s["eps"])
-    g = _mm(h2, w["mlp.w_gate"], quant)
-    u = _mm(h2, w["mlp.w_up"], quant)
-    return x + _mm(jax.nn.silu(g) * u, w["mlp.w_down"], quant)
-
-
 @functools.lru_cache(maxsize=None)
-def _programs(s_items: tuple, quant):
-    """Jitted pieces for one configuration (keyed by its sizes); the
-    sizes are static, the key and the data are arguments."""
+def _programs(arch, s_items: tuple, quant):
+    """Jitted pieces for one configuration (keyed by its module and its
+    sizes); the sizes are static, the key and the data are arguments.
+    ``layers`` holds one program per layer group, taking the layer's
+    index in its group."""
     s = dict(s_items)
     dt = jnp.dtype(s["dtype"])
+    mm = functools.partial(_mm, quant=quant)
 
     @jax.jit
     def embed(key, toks):
-        tok = W.global_weights(s, key, dt)["embed.tok"]
-        return tok[toks].astype(jnp.float32)
+        return arch.embed(s, W.global_weights(arch, s, key, dt), toks)
 
-    @jax.jit
-    def layer(key, l, X):
-        w = W.layer_weights(s, key, l, dt)
-        return jax.lax.map(lambda x: _layer(s, quant, w, x), X)
+    def layer_program(group):
+        @jax.jit
+        def layer(key, l, X):
+            w = W.layer_weights(arch, s, key, group, l, dt)
+            return jax.lax.map(lambda x: arch.layer(s, group, w, x, mm), X)
+        return layer
+
+    layers = {g: layer_program(g) for g, _, _ in arch.groups(s)}
 
     @jax.jit
     def final(key, X, read):
-        g = W.global_weights(s, key, dt)
+        g = W.global_weights(arch, s, key, dt)
         h = jnp.take_along_axis(X, read[..., None], axis=1)   # (B, n, d)
-        return _rms(h, g["ln_f.scale"].astype(jnp.float32), s["eps"])
+        return arch.final(s, g, h)
 
     @jax.jit
     def head(key, h, toks):
         """h: (N, d) -> per row (max logit, logit of toks, argmax)."""
-        g = W.global_weights(s, key, dt)
-        wh = (g["embed.tok"].T if s["tie"] else g["embed.lm_head"])
-        wh = wh.astype(jnp.float32)
+        wh = arch.head(s, W.global_weights(arch, s, key, dt))
         N = h.shape[0]
 
         def rows(args):
             hb, tb = args
-            lg = _mm(hb, wh, quant)
+            lg = mm(hb, wh)
             return (lg.max(-1), jnp.take_along_axis(lg, tb[:, None], 1)[:, 0],
                     lg.argmax(-1).astype(jnp.int32))
 
@@ -141,7 +89,13 @@ def _programs(s_items: tuple, quant):
         tb = toks.reshape(N // HEAD_ROWS, HEAD_ROWS)
         return tuple(o.reshape(N) for o in jax.lax.map(rows, (hb, tb)))
 
-    return embed, layer, final, head
+    return embed, layers, final, head
+
+
+def _arch_programs(conf: dict, quant):
+    arch = archs.of(conf)
+    s = arch.spec_of(conf)
+    return arch, s, _programs(arch, tuple(sorted(s.items())), quant)
 
 
 def _pad_to(n: int, m: int) -> int:
@@ -177,20 +131,19 @@ class Sample:
 
 def final_hidden(conf: dict, seed: int, sample: Sample, quant=None):
     """Normed final hidden states at the read positions: (B * n, d)."""
-    s = W.spec_of(conf)
-    embed, layer, final, _ = _programs(tuple(sorted(s.items())), quant)
+    arch, s, (embed, layers, final, _) = _arch_programs(conf, quant)
     key = W.root_key(seed)
     X = embed(key, jnp.asarray(sample.tokens))
-    for l in range(s["L"]):
-        X = layer(key, jnp.int32(l), X)
+    for group, n, _ in arch.groups(s):
+        for l in range(n):
+            X = layers[group](key, jnp.int32(l), X)
     h = final(key, X, jnp.asarray(sample.read))
     return h.reshape(-1, h.shape[-1])
 
 
 def head_stats(conf: dict, seed: int, h, toks, quant=None):
     """(max logit, logit of ``toks``, argmax) per row of ``h``."""
-    s = W.spec_of(conf)
-    head = _programs(tuple(sorted(s.items())), quant)[3]
+    *_, head = _arch_programs(conf, quant)[2]
     out = head(W.root_key(seed), h, jnp.asarray(toks, jnp.int32).reshape(-1))
     return tuple(np.asarray(o) for o in out)
 

@@ -3,7 +3,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a
-configuration (``bench/configs/<config>.json``) under a traffic mix
+configuration (``bench/configs/<config>.json``, whose ``bench_arch`` names
+its architecture module in ``bench/archs/``) under a traffic mix
 (``bench/traffic/<mix>.json``, overridden by ``bench/cells/<cell>.json``).
 The run, in one process that holds the chips:
 
@@ -59,8 +60,8 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 
 import numpy as np  # noqa: E402
 
+from bench import archs  # noqa: E402
 from bench import traffic as T  # noqa: E402
-from bench import weights as W  # noqa: E402
 from bench import work as WK  # noqa: E402
 
 DRAIN_S = 150.0          # longest wait for the window's requests to finish
@@ -179,7 +180,7 @@ class Tracer:
         if self.state == "on":
             self.poll(-1e18, time.monotonic())
 
-    def reduce(self, chips: int):
+    def reduce(self, chips: int, arch):
         from bench import trace_reduce as TR
         paths = sorted(Path(self.dir).rglob("*.xplane.pb"))
         try:
@@ -188,7 +189,7 @@ class Tracer:
             events = TR.load_xplane(paths[-1])
             span = TR.find_span(events, WINDOW_SPAN)
             # the generator's sleeps cover every gap and explain none
-            return TR.reduce(events, span, chips,
+            return TR.reduce(events, span, chips, arch.KERNELS,
                              skip_host=(WINDOW_SPAN, "bench.wait"))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
@@ -357,29 +358,32 @@ def enable_cache(jax) -> str:
     return path
 
 
-def span_work(s: dict, snaps: list, block: int, chunk: int) -> dict:
-    """Operations and bytes served between the two trace snapshots."""
+def span_work(arch, s: dict, snaps: list, block: int, chunk: int) -> dict:
+    """Operations and bytes served between the two trace snapshots: the
+    model FLOPs of the tokens decoded and of the prompt rows prefilled,
+    and each of the architecture's kernels' least work."""
     a, b = snaps
-    dec_keys = []
-    pre_model, pre_attn, pre_rows = 0.0, WK.Work(), 0
+    dec_keys, prefill = [], []
     for rid, (P, shared, o1, ftok) in b["reqs"].items():
         o0 = a["reqs"].get(rid, (P, 0, 0, None))[2]
         dec_keys.append(WK.decode_keys(P, o0, o1))
         if ftok is not None and a["t"] <= ftok <= b["t"]:
-            start = shared * block
-            pre_model += WK.model_flops(s, WK.prefill_keys(start, P), 1)
-            pre_attn = pre_attn + WK.prefill_attn(s, start, P, chunk)
-            pre_rows += P - start
+            prefill.append((shared * block, P))
     keys = np.concatenate(dec_keys) if dec_keys else np.zeros(0)
     rows = b["prefill_rows"] - a["prefill_rows"]
+    pre_rows = sum(P - start for start, P in prefill)
     scale = rows / pre_rows if pre_rows else 0.0
-    return dict(
+    pre_model = 0.0
+    for start, P in prefill:
+        pre_model += arch.model_flops(s, WK.prefill_keys(start, P), 1)
+    served = WK.Served(keys, prefill, chunk, scale)
+    work = dict(
         decode_tokens=int(np.size(keys)),
-        decode_model_flops=WK.model_flops(s, keys, np.size(keys)),
-        decode_attn=WK.decode_attn(s, keys),
+        decode_model_flops=arch.model_flops(s, keys, np.size(keys)),
         prefill_rows=rows,
-        prefill_model_flops=pre_model * scale,
-        prefill_attn=WK.Work(pre_attn.flops * scale, pre_attn.bytes * scale))
+        prefill_model_flops=pre_model * scale)
+    work.update({k: kern.work(s, served) for k, kern in arch.KERNELS.items()})
+    return work
 
 
 def run_cell(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
@@ -396,8 +400,10 @@ def run_cell(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
     peaks = peaks_for(device["kind"]) if device["platform"] == "tpu" else None
     print(f"device: {device}  compile cache: {enable_cache(jax)}", file=out)
     compiles = Compiles()
-    s = W.spec_of(conf)
-    cfg = P.model_config(conf)
+    arch = archs.of(conf)
+    s = arch.spec_of(conf)
+    CK.limits(conf)                 # every number compared is one it reads
+    cfg = arch.model_config(conf)
     dev0 = jax.devices()[0]
     fleet = P.build_fleet(conf, cfg, P.init_params(conf, cfg, seed, dev0))
     eng = fleet.engines[0]
@@ -483,8 +489,8 @@ def run_cell(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
     device["memory_peak_bytes"] = peak_bytes
     result = {"correct": False, "attempted": res["n_win"], "failed": n_fail}
     if trace:
-        red = driver.tracer.reduce(cell["chips"])
-        work = span_work(s, driver.tracer.snaps, block, chunk)
+        red = driver.tracer.reduce(cell["chips"], arch)
+        work = span_work(arch, s, driver.tracer.snaps, block, chunk)
         ctx = dict(stats=stats, trace=red, work=work, peaks=peaks, spec=s,
                    chips=cell["chips"], out=out)
         metrics = {}

@@ -4,6 +4,7 @@ prefixes (seeded starts) and several prompts per step (budget-cut
 chunks), and compare the engine's own record of what it compiled."""
 import numpy as np
 
+from bench import archs
 from bench import program as P
 from bench.tests import tiny
 
@@ -12,7 +13,7 @@ def test_buckets_cover_what_the_engine_serves():
     import jax
     from repro.serving.engine import Request
     conf = tiny.conf()
-    cfg = P.model_config(conf)
+    cfg = archs.of(conf).model_config(conf)
     fleet = P.build_fleet(conf, cfg, P.init_params(conf, cfg, 4,
                                                    jax.devices()[0]))
     eng = fleet.engines[0]

@@ -3,6 +3,10 @@ reference, and the fp8 control, judged in the program's place at the
 same positions, comes out not correct."""
 import re
 
+import numpy as np
+import pytest
+
+from bench import check as CK
 from bench.tests import tiny
 
 
@@ -31,3 +35,21 @@ def test_sound_closed_loop_run_is_correct(monkeypatch):
     assert 0 <= gap["value"] <= gap["limit"]
     assert "window opens: set-up" in printed
     assert res["metrics"]["output_tok_s"]["value"] > 0
+
+
+GAPS = np.array([0.0] * 16 + [0.01, 0.2, 0.3, 0.5])
+
+
+@pytest.mark.parametrize("name,value", [
+    ("logit_gap", 0.5), ("gap_p50", 0.0), ("gap_p90", 0.2 + 0.1 * 0.1),
+    ("gap_p95", 0.31)])
+def test_compared_numbers(name, value):
+    """Each number a configuration's ``check`` stanza may name, read from
+    the same gaps; a dense stanza compares the widest gap alone."""
+    assert CK.number(name)(GAPS) == pytest.approx(value, abs=1e-12)
+    assert CK.limits({"check": {f"{name}_limit": 1.0, "why": "-"}}) == {
+        name: 1.0}
+    assert CK.limits(tiny.conf()) == {"logit_gap": 0.05}
+    for bad in ("gap_p0", "gap_p100", "mean_gap", "why"):
+        with pytest.raises(ValueError):
+            CK.limits({"check": {f"{bad}_limit": 1.0}})

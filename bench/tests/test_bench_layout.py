@@ -6,8 +6,9 @@ from pathlib import Path
 
 import pytest
 
+from bench import archs
+from bench import check as CK
 from bench import traffic as T
-from bench import weights as W
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -32,8 +33,11 @@ def test_config_resolves(conf):
     data = json.loads((ROOT / conf["file"]).read_text())
     assert data["name"] == conf["name"] and data["source"] == conf["source"]
     assert sorted(conf["reduced"]) == sorted(data["reduced"])
-    W.spec_of(data)                       # every size the harness reads
-    assert 0 < data["check"]["logit_gap_limit"]
+    arch = archs.of(data)                 # bench/archs/<bench_arch>.py
+    assert (ROOT / "bench" / "archs" / f"{data['bench_arch']}.py").is_file()
+    s = arch.spec_of(data)                # every size the harness reads
+    assert s["V"] == data["vocab_size"] and s["dtype"] == data["torch_dtype"]
+    assert all(0 < v for v in CK.limits(data).values())
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])

@@ -1,13 +1,13 @@
-"""bench/reference.py against the program at a test size: its logits
-equal the program's dense forward run in float32 on the same weights,
-for both configurations' switches (QKV bias with a tied head;
-qk_norm with an untied head)."""
+"""bench/reference.py, running the equations of bench/archs/qwen_dense.py,
+against the program at a test size: its logits equal the program's dense
+forward run in float32 on the same weights, for both configurations'
+switches (QKV bias with a tied head; qk_norm with an untied head)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import program as P
+from bench.archs import qwen_dense as QD
 from bench import reference as R
 from bench import weights as W
 from bench.tests import tiny
@@ -22,12 +22,12 @@ def test_reference_matches_the_program_in_float32(bias, qk_norm, tie):
     conf["architecture"] = {"qkv_bias": bias, "qk_norm": qk_norm}
     conf["tie_word_embeddings"] = tie
     seed = 2 ** 33 + 5
-    s = W.spec_of(conf)
-    cfg = P.model_config(conf).replace(compute_dtype="float32",
-                                       param_dtype="float32")
+    s = QD.spec_of(conf)
+    cfg = QD.model_config(conf).replace(compute_dtype="float32",
+                                        param_dtype="float32")
     params = jax.tree_util.tree_map(
         lambda x: x.astype(jnp.float32),
-        W.make_tree(s, W.root_key(seed), jnp.bfloat16))
+        W.make_tree(QD, s, W.root_key(seed), jnp.bfloat16))
     toks = np.random.default_rng(0).integers(0, s["V"], 97).astype(np.int32)
     lg, _ = fns_for(cfg).forward(cfg, params, {"tokens": jnp.asarray(toks)[None]})
     lg = np.asarray(lg[0, :-1], np.float64)       # (S - 1, V): reads 0..S-2

@@ -6,6 +6,7 @@ from pathlib import Path
 import pytest
 
 from bench import trace_reduce as TR
+from bench.archs import qwen_dense as QD
 
 DATA = Path(__file__).resolve().parent / "data"
 DEV = "/device:TPU:0"
@@ -28,7 +29,8 @@ def test_busy_idle_kernels_programs_and_blame():
         ev("python", "np.asarray", 4.5, 0.2, plane="/host:CPU"),
         ev("python", "bench.trace_span", -1.0, 20.0, plane="/host:CPU"),
     ]
-    red = TR.reduce(events, (0.0, 10.0), 1, skip_host=("bench.trace_span",))
+    red = TR.reduce(events, (0.0, 10.0), 1, QD.KERNELS,
+                    skip_host=("bench.trace_span",))
     assert red.window_s == 10.0
     assert red.busy_s == pytest.approx(4.0 + 2.0)     # the loop covers 0..4
     assert red.idle_share == pytest.approx(0.4)
@@ -48,10 +50,10 @@ def test_span_clips_and_averages_over_chips():
     events = [ev("XLA Ops", "f", -1.0, 3.0),
               ev("XLA Ops", "f", 0.0, 1.0, plane="/device:TPU:1"),
               ev("XLA Ops", "f", 0.0, 1.0, plane="/device:TPU:2")]
-    red = TR.reduce(events, (0.0, 4.0), 2)
+    red = TR.reduce(events, (0.0, 4.0), 2, QD.KERNELS)
     assert red.busy_s == pytest.approx((2.0 + 1.0) / 2)
     with pytest.raises(ValueError):
-        TR.reduce(events[:1], (0.0, 4.0), 2)
+        TR.reduce(events[:1], (0.0, 4.0), 2, QD.KERNELS)
 
 
 def test_find_span():
@@ -66,7 +68,7 @@ def test_recorded_v5e_trace():
     step (32 slots) and the tail of a prefill chunk."""
     events = TR.load_events(DATA / "trace_v5e.json")
     span = TR.find_span(events, "bench.trace_span")
-    red = TR.reduce(events, span, 1, skip_host=("bench.trace_span",))
+    red = TR.reduce(events, span, 1, QD.KERNELS, skip_host=("bench.trace_span",))
     assert red.window_s == pytest.approx(0.15)
     assert red.busy_s == pytest.approx(0.141898083, rel=1e-6)
     assert red.kernel_s["decode_attn"] == pytest.approx(0.072605281, rel=1e-6)
@@ -79,7 +81,7 @@ def test_recorded_v5e_trace():
                                                    rel=0.05)
     # ... which explains nothing: without it, the engine's pull of the
     # step's logits to the host takes most of the idle time
-    red = TR.reduce(events, span, 1,
+    red = TR.reduce(events, span, 1, QD.KERNELS,
                     skip_host=("bench.trace_span", "bench.wait"))
     assert max(red.gaps, key=red.gaps.get) == "np.asarray(jax.Array)"
     assert sum(red.gaps.values()) == pytest.approx(0.0081015, rel=1e-3)

@@ -11,8 +11,8 @@ from bench import traffic as T
 DATA = Path(__file__).resolve().parent / "data"
 
 
-def conf() -> dict:
-    return json.loads((DATA / "tiny.json").read_text())
+def conf(name: str = "tiny") -> dict:
+    return json.loads((DATA / f"{name}.json").read_text())
 
 
 # the end-to-end metrics each loop reports, as BENCHMARK.json names them
@@ -46,12 +46,13 @@ def cell(name: str):
 
 
 def run(monkeypatch, name: str, seed: int, *, seconds: float = 2.0,
-        control: bool = False) -> tuple[dict, str]:
-    """One run of a tiny cell; returns (result, what it printed)."""
+        control: bool = False, config: str = "tiny") -> tuple[dict, str]:
+    """One run of a tiny cell (of configuration ``data/<config>.json``);
+    returns (result, what it printed)."""
     monkeypatch.setattr(R, "enable_cache", lambda jax: "off")
     monkeypatch.setattr(R, "DRAIN_S", 20.0)
     bench, c, mix = cell(name)
     out, err = io.StringIO(), io.StringIO()
-    res = R.run_cell(bench, c, conf(), mix, seed, seconds, False,
+    res = R.run_cell(bench, c, conf(config), mix, seed, seconds, False,
                      control=control, require_tpu=False, out=out, err=err)
     return res, out.getvalue()

@@ -13,7 +13,9 @@ recorded trace (``bench/tests/data``).
   idle is the rest of the span.
 - A kernel is found by its name among the operations; a program is
   classed by the kernels that ran inside it (the step programs are
-  jitted lambdas, so their own names say nothing).
+  jitted lambdas, so their own names say nothing).  The kernels, each
+  with its name substrings and the program class it marks, are the
+  cell's architecture module's ``KERNELS`` (``bench/archs/``).
 - Each idle gap is put down to the innermost host span that covers it,
   on any host thread: the host was busy with that when the device
   waited.
@@ -27,17 +29,9 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from pathlib import Path
 
-# kernel -> substrings that name it among the device operations (the
-# Pallas calls appear as custom-calls named after their jitted wrappers)
-KERNELS = {
-    "decode_attn": ("paged_decode_attention",),
-    "prefill_attn": ("paged_prefill_attention",),
-}
 # control flow whose events span the operations inside them: busy time
 # takes their union anyway, the breakdown leaves them out
 CONTAINERS = ("while", "conditional", "call")
-# program class -> the kernel whose presence marks it
-PROGRAMS = {"decode": "decode_attn", "prefill": "prefill_attn"}
 DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 MIN_GAP_S = 20e-6      # shorter idle slivers are launch jitter, not gaps
@@ -137,19 +131,25 @@ def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     return [(a, b) for a, b in out]
 
 
-def kernel_of(name: str) -> str | None:
-    for k, keys in KERNELS.items():
+def kernel_of(name: str, kernels: dict) -> str | None:
+    for k, keys in kernels.items():
         if any(s in name for s in keys):
             return k
     return None
 
 
 def reduce(events: list[Event], span: tuple[float, float], chips: int,
-           skip_host: tuple[str, ...] = ()) -> Reduced:
+           kernels: dict, skip_host: tuple[str, ...] = ()) -> Reduced:
     """Reduce the events of devices ``0 .. chips - 1`` inside ``span``.
-    Host spans named in ``skip_host`` (the harness's own span around the
-    whole traced window) never take the blame for a gap."""
+    ``kernels`` is an architecture module's ``KERNELS``: each kernel with
+    the substrings that name it among the operations and the program
+    class its presence marks (the first class that matches wins).  Host
+    spans named in ``skip_host`` (the harness's own span around the whole
+    traced window) never take the blame for a gap."""
     t0, t1 = span
+    programs = {kern.program: k for k, kern in kernels.items()
+                if kern.program is not None}
+    kernels = {k: kern.names for k, kern in kernels.items()}
     by_dev: dict[int, dict[str, list[Event]]] = defaultdict(
         lambda: defaultdict(list))
     host = []
@@ -179,7 +179,7 @@ def reduce(events: list[Event], span: tuple[float, float], chips: int,
             kind = op_kind(e.name)
             if kind not in CONTAINERS:
                 op_s[kind] += b - a
-            k = kernel_of(e.name)
+            k = kernel_of(e.name, kernels)
             if k:
                 kernel_s[k] += b - a
         union = _union(busy)
@@ -192,8 +192,8 @@ def reduce(events: list[Event], span: tuple[float, float], chips: int,
                 continue
             i0 = bisect.bisect_left(starts, mod.start)
             i1 = bisect.bisect_right(starts, mod.end)
-            inside = {kernel_of(e.name) for e in ops[i0:i1]}
-            cls = next((p for p, k in PROGRAMS.items() if k in inside),
+            inside = {kernel_of(e.name, kernels) for e in ops[i0:i1]}
+            cls = next((p for p, k in programs.items() if k in inside),
                        "other")
             program_s[cls] += b - a
         edges = [t0] + [x for ab in union for x in ab] + [t1]
