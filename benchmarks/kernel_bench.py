@@ -19,6 +19,7 @@ import repro.kernels.conv2d.ops        # noqa: F401  (register_kernel)
 import repro.kernels.decode_attention.ops  # noqa: F401
 import repro.kernels.flash_attention.ops   # noqa: F401
 import repro.kernels.matmul.ops        # noqa: F401
+import repro.kernels.moe_expert_ffn.ops  # noqa: F401
 import repro.kernels.prefill_attention.ops  # noqa: F401
 import repro.kernels.ssm_scan.ops      # noqa: F401
 from repro.kernels.conv2d.kernel import conv2d
@@ -32,6 +33,9 @@ from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.matmul.kernel import matmul
 from repro.kernels.matmul.ref import matmul_ref
+from repro.kernels.moe_expert_ffn.kernel import (group_offsets,
+                                                 moe_expert_ffn, rows_bound)
+from repro.kernels.moe_expert_ffn.ref import moe_expert_ffn_ref
 from repro.kernels.prefill_attention.kernel import paged_prefill_attention
 from repro.kernels.prefill_attention.ref import paged_prefill_attention_ref
 from repro.kernels.ssm_scan.kernel import ssm_scan
@@ -49,7 +53,9 @@ COVERAGE = {
     "matmul": "matmul_err",
     "flash_attention": "flash_err",
     "decode_attention": "decode_err",
-    "paged_decode_attention": "paged_decode_err",
+    "paged_decode_attention": ("paged_decode_err",
+                               "paged_decode_latent_err"),
+    "moe_expert_ffn": "moe_expert_ffn_err",
     "paged_prefill_attention": ("paged_prefill_err",
                                 "paged_prefill_verify_err",
                                 "paged_prefill_verify_int8_err"),
@@ -152,6 +158,35 @@ def _kernel_errs(interpret: bool = True) -> dict:
                                 interpret=interpret)
         - paged_prefill_attention_ref(qv, kq, vq, vtables, vq_start, vlens,
                                       k_scale=ksc, v_scale=vsc)).max())
+
+    # latent pool (MLA): one entry per token, values its first lanes
+    lp = jax.random.normal(ks[6], (1 + 2 * mb, 1, bs, 128))
+    ql = jax.random.normal(ks[5], (2, 8, 128))
+    out["paged_decode_latent_err"] = float(jnp.abs(
+        paged_decode_attention(ql, lp, None, tables, plens, scale=0.125,
+                               v_dim=96, interpret=interpret)
+        - paged_decode_attention_ref(ql, lp, None, tables, plens,
+                                     scale=0.125, v_dim=96)).max())
+
+    # held experts' grouped SwiGLU: 3 groups of ragged sizes, one empty
+    sizes = jnp.array([5, 0, 19], jnp.int32)
+    rows = rows_bound(24, 1, 3, 16)
+    starts = group_offsets(sizes, 16)
+    valid = jnp.zeros((rows,), bool)
+    for e in range(3):
+        valid = valid | ((jnp.arange(rows) >= starts[e])
+                         & (jnp.arange(rows) < starts[e] + sizes[e]))
+    xs = jnp.where(valid[:, None], jax.random.normal(ks[0], (rows, 128)),
+                   0).astype(jnp.bfloat16)
+    wg, wu = (0.1 * jax.random.normal(k_, (3, 128, 256)) for k_ in ks[1:3])
+    wd = 0.1 * jax.random.normal(ks[3], (3, 256, 128))
+    ws = [w.astype(jnp.bfloat16) for w in (wg, wu, wd)]
+    got = moe_expert_ffn(xs, *ws, sizes, tm=16, group_rows=24,
+                         interpret=interpret)
+    want = moe_expert_ffn_ref(xs, *ws, sizes, tm=16)
+    out["moe_expert_ffn_err"] = float(jnp.abs(jnp.where(
+        valid[:, None], got.astype(jnp.float32) - want.astype(jnp.float32),
+        0)).max())
 
     ld = -jax.nn.softplus(jax.random.normal(ks[6], (1, 256, 4)))
     lg = 0.1 * jax.random.normal(ks[7], (1, 256, 4))

@@ -29,6 +29,47 @@ class MoEConfig:
     # DeepSeek style).
     norm_topk_prob: bool = True
     router_aux_loss_weight: float = 0.001
+    # Router scores: "softmax" over the experts, or "sigmoid" per expert
+    # (DeepSeek-V3).  With ``router_bias`` a per-expert correction bias
+    # (the weight leaf ``router_bias``, shape (E,)) is added to the scores
+    # when choosing the top k, not when weighting them (``noaux_tc``).
+    scoring: str = "softmax"
+    router_bias: bool = False
+    # The chosen experts' weights are multiplied by this after the top-k
+    # normalisation (DeepSeek-V3's ``routed_scaling_factor``).
+    routed_scaling: float = 1.0
+    # The experts this layer holds, (first, count), of ``num_experts``: its
+    # share under expert parallelism.  Set, the layer holds only their
+    # weights and computes their share of each token without a capacity
+    # (no token dropped: ``moe.moe_held``); None, the layer holds every
+    # expert and dispatches with a capacity (``moe.moe_apply``).
+    held: tuple[int, int] | None = None
+
+    @property
+    def held_count(self) -> int:
+        return self.held[1] if self.held else self.num_experts
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA:
+    keys and values are read back from one ``kv_lora_rank``-wide latent
+    per token plus a ``qk_rope_head_dim``-wide RoPE key shared by every
+    head."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of a token's cache entry: the latent and the RoPE key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -93,6 +134,7 @@ class ModelConfig:
     shared_attn_every: int = 0
     # --- sub-configs ---
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None     # latent attention in place of GQA
     ssm: SSMConfig | None = None
     xlstm: XLSTMConfig | None = None
     encdec: EncDecConfig | None = None

@@ -27,6 +27,11 @@ own, so the wrapper gathers them by table into lanes, ``(B, K, mb*bs)``
 (4 bytes per row and head, over the whole table), and the kernel copies
 the ``(K, P*bs)`` slice of each live group.
 
+The value pool may be omitted: values are then the first ``Dv`` lanes of
+the key rows, and only the key pages are copied.  Latent attention (MLA)
+decodes that way, all query heads against one latent entry per token
+(K = 1), with the softmax scale its caller gives.
+
 Oracle: `models.layers.attention.chunked_attention` with kv_len masking
 (`ref.decode_attention_ref` / `ref.paged_decode_attention_ref`).
 """
@@ -143,13 +148,18 @@ def _run(copies, start: bool):
             cp.wait()
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
-                  bs: int, P: int, B: int, softcap: float, quant: bool):
+def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, *rest, scale: float,
+                  bs: int, P: int, B: int, softcap: float, quant: bool,
+                  split_v: bool, Dv: int):
+    rest = list(rest)
+    v_hbm = rest.pop(0) if split_v else None
     if quant:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
          buf_ref) = rest
-    else:
+    elif split_v:
         o_ref, k_buf, v_buf, sem, buf_ref = rest
+    else:
+        o_ref, k_buf, sem, buf_ref = rest
     K = q_ref.shape[1]
     rows = P * bs
     b = pl.program_id(0)
@@ -164,8 +174,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
 
         def one_page(j, _):
             page = bt_ref[slot, g * P + j]
-            _run([copy(k_hbm.at[page], k_buf.at[buf, j]),
-                  copy(v_hbm.at[page], v_buf.at[buf, j])], start)
+            cps = [copy(k_hbm.at[page], k_buf.at[buf, j])]
+            if split_v:
+                cps.append(copy(v_hbm.at[page], v_buf.at[buf, j]))
+            _run(cps, start)
 
         lax.fori_loop(0, jnp.clip(n_pages - g * P, 0, P), one_page, None)
 
@@ -212,7 +224,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
             m_prev, l_prev, acc_prev = carry[h]
             q = q_ref[0, h]                                   # (G, D)
             k = k_buf[buf, :, h].astype(q.dtype).reshape(rows, -1)
-            v = v_buf[buf, :, h].astype(q.dtype).reshape(rows, -1)
+            if split_v:
+                v = v_buf[buf, :, h].astype(q.dtype).reshape(rows, -1)
+            else:
+                v = k[:, :Dv]
             s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             if quant:
@@ -234,10 +249,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
             out.append((m_new, l_new, acc_new))
         return tuple(out)
 
-    G, D = q_ref.shape[2], q_ref.shape[3]
+    G = q_ref.shape[2]
     init = tuple((jnp.full((G, 1), NEG_INF, jnp.float32),
                   jnp.zeros((G, 1), jnp.float32),
-                  jnp.zeros((G, D), jnp.float32)) for _ in range(K))
+                  jnp.zeros((G, Dv), jnp.float32)) for _ in range(K))
     final = lax.fori_loop(0, n_groups, body, init)
 
     @pl.when((n_groups == 0) & has_next)
@@ -250,27 +265,36 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
         o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
-def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+@functools.partial(jax.jit, static_argnames=("softcap", "scale", "v_dim",
+                                             "interpret"))
+def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array | None,
                            block_tables: jax.Array, lengths: jax.Array, *,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
                            softcap: float = 0.0,
+                           scale: float | None = None,
+                           v_dim: int | None = None,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, D); k_pool/v_pool: (N, K, bs, D) global block pool;
+    """q: (B, H, D); k_pool/v_pool: (N, K, bs, D) global block pool, or
+    ``v_pool`` None: values are the first ``v_dim`` lanes of the key rows;
     block_tables: (B, max_blocks) physical block per logical block;
-    lengths: (B,) valid rows; k_scale/v_scale: (N, K, bs) for int8 pools.
+    lengths: (B,) valid rows; k_scale/v_scale: (N, K, bs) for int8 pools;
+    ``scale``: the softmax scale (default 1 / sqrt(D)).
 
-    Returns (B, H, D).  Grid (B,): one step per slot serves all its KV
-    heads; the pools stay in HBM and the kernel copies each slot's live
-    pages, P at a time, into double-buffered VMEM.
+    Returns (B, H, Dv), Dv = D with a value pool, else ``v_dim``.  Grid
+    (B,): one step per slot serves all its KV heads; the pools stay in HBM
+    and the kernel copies each slot's live pages, P at a time, into
+    double-buffered VMEM.
     """
     B, H, D = q.shape
     N, K, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
     G = H // K
+    split_v = v_pool is not None
+    Dv = D if split_v else v_dim
     P = _pages_per_group(K, bs, D, k_pool.dtype.itemsize, mb)
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
     qg = q.reshape(B, K, G, D)
     quant = k_scale is not None
 
@@ -278,10 +302,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         return (b, 0, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, K, G, D), q_map), hbm, hbm]
-    args = [qg, k_pool, v_pool]
-    scratch = [pltpu.VMEM((2, P, K, bs, D), k_pool.dtype),
-               pltpu.VMEM((2, P, K, bs, D), v_pool.dtype)]
+    in_specs = [pl.BlockSpec((1, K, G, D), q_map), hbm]
+    args = [qg, k_pool]
+    scratch = [pltpu.VMEM((2, P, K, bs, D), k_pool.dtype)]
+    if split_v:
+        in_specs.append(hbm)
+        args.append(v_pool)
+        scratch.append(pltpu.VMEM((2, P, K, bs, D), v_pool.dtype))
     if quant:
         # row scales by slot, in lanes: (B, K, width*bs), width a whole
         # number of groups
@@ -301,18 +328,19 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, K, G, D), q_map),
+        out_specs=pl.BlockSpec((1, K, G, Dv), q_map),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=bs, P=P, B=B,
-                          softcap=softcap, quant=quant),
+                          softcap=softcap, quant=quant, split_v=split_v,
+                          Dv=Dv),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, Dv), q.dtype),
         # a step prefetches the next slot's first group: run them in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
-    return out.reshape(B, H, D)
+    return out.reshape(B, H, Dv)

@@ -18,6 +18,10 @@ the query, output and accumulator tiles held in VMEM stay the same size
 however long the chunk is.  A tile skips the blocks wholly past the valid
 rows and the blocks wholly after its last query position.
 
+The value pool may be omitted: values are then the first ``Dv`` lanes of
+the key block (latent attention: all query heads against one latent entry
+per token, with the softmax scale its caller gives).
+
 Oracle: `ref.paged_prefill_attention_ref` (gather + chunked attention).
 """
 from __future__ import annotations
@@ -35,9 +39,11 @@ NEG_INF = -1e30
 _TILE_ROWS = 1024
 
 
-def _prefill_kernel(bt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+def _prefill_kernel(bt_ref, qs_ref, len_ref, q_ref, k_ref, *rest,
                     scale: float, bs: int, mb: int, G: int, tq: int,
-                    softcap: float, quant: bool):
+                    softcap: float, quant: bool, split_v: bool, Dv: int):
+    rest = list(rest)
+    v_ref = rest.pop(0) if split_v else None
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -64,7 +70,8 @@ def _prefill_kernel(bt_ref, qs_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     def _update():
         q = q_ref[0, 0, :, :]                     # (tq*G, D)
         k = k_ref[0, 0, :, :].astype(q.dtype)     # (bs, D)
-        v = v_ref[0, 0, :, :].astype(q.dtype)
+        v = (v_ref[0, 0, :, :].astype(q.dtype) if split_v
+             else k[:, :Dv])
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if quant:
@@ -106,30 +113,38 @@ def _query_tile(C: int, G: int) -> int:
     return math.gcd(C, 1 << (max(1, _TILE_ROWS // G).bit_length() - 1))
 
 
-@functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
+@functools.partial(jax.jit, static_argnames=("softcap", "scale", "v_dim",
+                                             "interpret"))
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
-                            v_pool: jax.Array, block_tables: jax.Array,
+                            v_pool: jax.Array | None,
+                            block_tables: jax.Array,
                             q_start: jax.Array, lengths: jax.Array, *,
                             k_scale: jax.Array | None = None,
                             v_scale: jax.Array | None = None,
                             softcap: float = 0.0,
+                            scale: float | None = None,
+                            v_dim: int | None = None,
                             interpret: bool = False) -> jax.Array:
     """q: (B, C, H, D) query chunk at positions ``q_start .. q_start+C-1``;
-    k_pool/v_pool: (N, K, bs, D) global block pool; block_tables:
+    k_pool/v_pool: (N, K, bs, D) global block pool, or ``v_pool`` None:
+    values are the first ``v_dim`` lanes of the key rows; block_tables:
     (B, max_blocks); q_start: (B,) chunk origin; lengths: (B,) valid rows
-    incl. the chunk; k_scale/v_scale: (N, K, bs) for int8 pools.
+    incl. the chunk; k_scale/v_scale: (N, K, bs) for int8 pools;
+    ``scale``: the softmax scale (default 1 / sqrt(D)).
 
-    Returns (B, C, H, D).  Grid (B, K, C / tq, max_blocks); tables,
-    q_start, and lengths are scalar-prefetch operands, so the k/v
-    BlockSpec index maps DMA each sequence's physical blocks in logical
-    order.
+    Returns (B, C, H, Dv), Dv = D with a value pool, else ``v_dim``.  Grid
+    (B, K, C / tq, max_blocks); tables, q_start, and lengths are
+    scalar-prefetch operands, so the k/v BlockSpec index maps DMA each
+    sequence's physical blocks in logical order.
     """
     B, C, H, D = q.shape
     N, K, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
     G = H // K
     tq = _query_tile(C, G)
-    scale = 1.0 / (D ** 0.5)
+    split_v = v_pool is not None
+    Dv = D if split_v else v_dim
+    scale = 1.0 / (D ** 0.5) if scale is None else scale
     qg = (q.reshape(B, C, K, G, D).transpose(0, 2, 1, 3, 4)
           .reshape(B, K, C * G, D))
     quant = k_scale is not None
@@ -146,9 +161,11 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
     in_specs = [
         pl.BlockSpec((1, 1, tq * G, D), q_map),
         pl.BlockSpec((1, 1, bs, D), kv_map),
-        pl.BlockSpec((1, 1, bs, D), kv_map),
     ]
-    args = [qg, k_pool, v_pool]
+    args = [qg, k_pool]
+    if split_v:
+        in_specs.append(pl.BlockSpec((1, 1, bs, D), kv_map))
+        args.append(v_pool)
     if quant:
         in_specs += [pl.BlockSpec((1, K, bs), sc_map),
                      pl.BlockSpec((1, K, bs), sc_map)]
@@ -158,21 +175,22 @@ def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
         num_scalar_prefetch=3,
         grid=(B, K, C // tq, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, tq * G, D), q_map),
+        out_specs=pl.BlockSpec((1, 1, tq * G, Dv), q_map),
         scratch_shapes=[
             pltpu.VMEM((tq * G, 1), jnp.float32),
             pltpu.VMEM((tq * G, 1), jnp.float32),
-            pltpu.VMEM((tq * G, D), jnp.float32),
+            pltpu.VMEM((tq * G, Dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=scale, bs=bs, mb=mb, G=G,
-                          tq=tq, softcap=softcap, quant=quant),
+                          tq=tq, softcap=softcap, quant=quant,
+                          split_v=split_v, Dv=Dv),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, C * G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, C * G, Dv), q.dtype),
         interpret=interpret,
         name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), q_start.astype(jnp.int32),
       lengths.astype(jnp.int32), *args)
-    return (out.reshape(B, K, C, G, D).transpose(0, 2, 1, 3, 4)
-            .reshape(B, C, H, D))
+    return (out.reshape(B, K, C, G, Dv).transpose(0, 2, 1, 3, 4)
+            .reshape(B, C, H, Dv))

@@ -9,7 +9,8 @@ from repro.models.layers.attention import chunked_attention
 
 def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, q_start,
                                 lengths, *, k_scale=None, v_scale=None,
-                                softcap=0.0, chunk=1024):
+                                softcap=0.0, scale=None, v_dim=None,
+                                chunk=1024):
     """Multi-row query chunk vs block-table-gathered pool KV.
 
     q: (B, C, H, D) — a prompt chunk whose row ``o`` sits at absolute
@@ -23,13 +24,14 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, q_start,
     Causality makes row ``o`` attend to every seeded/earlier row plus the
     chunk rows at or before it; table entries past ``lengths`` (trash or
     spare decode blocks) sit at higher kv positions and are masked out.
-    Returns (B, C, H, D).
+    ``v_pool`` None: values are the first ``v_dim`` lanes of the key rows;
+    ``scale`` the softmax scale.  Returns (B, C, H, Dv).
     """
     B, C, H, D = q.shape
     N, K, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
     k = k_pool[block_tables]                     # (B, mb, K, bs, D)
-    v = v_pool[block_tables]
+    v = k[..., :v_dim] if v_pool is None else v_pool[block_tables]
     if k_scale is not None:
         k = (k.astype(jnp.float32)
              * k_scale[block_tables][..., None]).astype(q.dtype)
@@ -37,10 +39,10 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, block_tables, q_start,
              * v_scale[block_tables][..., None]).astype(q.dtype)
     S = mb * bs
     k = k.swapaxes(2, 3).reshape(B, S, K, D).astype(q.dtype)
-    v = v.swapaxes(2, 3).reshape(B, S, K, D).astype(q.dtype)
+    v = v.swapaxes(2, 3).reshape(B, S, K, -1).astype(q.dtype)
     q_pos = q_start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
     out = chunked_attention(
         q, k, v, causal=True, q_positions=q_pos,
         kv_positions=jnp.arange(S, dtype=jnp.int32),
-        kv_len=lengths, softcap=softcap, chunk=chunk)
+        kv_len=lengths, softcap=softcap, scale=scale, chunk=chunk)
     return out

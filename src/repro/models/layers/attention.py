@@ -27,6 +27,8 @@ NEG_INF = -1e30
 
 def attention_table(cfg, d_model: int | None = None):
     """Parameter table for one attention block."""
+    if cfg.mla is not None:
+        return mla_table(cfg)
     d = d_model or cfg.d_model
     hd = cfg.resolved_head_dim
     # 1/sqrt(fan_in) over the contracted dims: the default would take the
@@ -49,6 +51,95 @@ def attention_table(cfg, d_model: int | None = None):
         t["q_norm"] = scale((hd,), (None,))
         t["k_norm"] = scale((hd,), (None,))
     return t
+
+
+def mla_table(cfg):
+    """Latent attention (DeepSeek-V2/V3, no query LoRA): ``wq`` gives each
+    head a ``qk_nope``-wide query and a ``qk_rope``-wide RoPE query;
+    ``wkv_a`` gives the token's latent and its RoPE key, shared by every
+    head; ``kv_norm`` scales the normed latent; ``wkv_b`` reads each head's
+    key (``qk_nope`` wide) and value (``v_head_dim``) back from it."""
+    m, d, H = cfg.mla, cfg.d_model, cfg.num_heads
+    r, dn, dv = m.kv_lora_rank, m.qk_nope_head_dim, m.v_head_dim
+    return {
+        "wq": weight((d, H, m.qk_head_dim), ("embed", "heads", None),
+                     d ** -0.5),
+        "wkv_a": weight((d, m.latent_dim), ("embed", None), d ** -0.5),
+        "kv_norm": scale((r,), (None,)),
+        "wkv_b": weight((r, H, dn + dv), (None, "heads", None), r ** -0.5),
+        "wo": weight((H, dv, d), ("heads", None, "embed"), (H * dv) ** -0.5),
+    }
+
+
+def mla_entry_dim(cfg) -> int:
+    """Lanes of a latent cache entry as stored: the latent and the RoPE
+    key, zero-padded to a multiple of 128 lanes (Mosaic copies a page only
+    in whole 128-lane tiles; the chip's tiled layout pads the row to that
+    width in HBM anyway)."""
+    return -(-cfg.mla.latent_dim // 128) * 128
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: one over the root of a head's whole query width."""
+    return cfg.mla.qk_head_dim ** -0.5
+
+
+def mla_project(cfg, params, x: jax.Array, positions: jax.Array):
+    """x: (B, S, D) -> q_nope (B, S, H, dn), q_pe (B, S, H, dr) roped,
+    c (B, S, r) the normed latent, k_pe (B, S, 1, dr) the roped shared key.
+    RoPE rotates the halves of the rope columns as they are laid out."""
+    m, dt = cfg.mla, x.dtype
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
+    q_nope, q_pe = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
+    kv = jnp.einsum("bsd,dk->bsk", x, params["wkv_a"].astype(dt))
+    c = head_rmsnorm(params["kv_norm"], kv[..., :m.kv_lora_rank],
+                     cfg.norm_eps)
+    k_pe = kv[..., None, m.kv_lora_rank:]
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)
+    return q_nope, q_pe, c, k_pe
+
+
+def mla_attention(cfg, params, x: jax.Array, positions: jax.Array, *,
+                  chunk: int = 1024) -> jax.Array:
+    """The published form over a whole sequence: every head's key
+    ``[c W_kvb,k ; k_pe]`` and value ``c W_kvb,v`` made explicit, then
+    causal attention.  Returns the heads' values (B, S, H, v_head_dim)."""
+    m = cfg.mla
+    q_nope, q_pe, c, k_pe = mla_project(cfg, params, x, positions)
+    kvb = jnp.einsum("bsr,rhk->bshk", c, params["wkv_b"].astype(c.dtype))
+    k_nope, v = kvb[..., :m.qk_nope_head_dim], kvb[..., m.qk_nope_head_dim:]
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3] + k_pe.shape[3:])],
+        -1)
+    return chunked_attention(q, k, v, causal=True, q_positions=positions,
+                             kv_positions=positions, chunk=chunk)
+
+
+def mla_absorb(cfg, params, q_nope, q_pe, c, k_pe):
+    """The absorbed form's query and cache entry.  Each head's no-RoPE
+    query is taken into the latent space, ``q_lat = q_nope W_kvb,k^T``, so
+    its score against a token is ``q_lat . c + q_pe . k_pe`` over the
+    token's one entry ``[c ; k_pe]``.  Returns the queries (B, S, H, W)
+    and the entries (B, S, 1, W), both zero-padded to the stored width W
+    (:func:`mla_entry_dim`)."""
+    m = cfg.mla
+    w = mla_entry_dim(cfg)
+    wk = params["wkv_b"][..., :m.qk_nope_head_dim].astype(q_nope.dtype)
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wk)
+    pad = [(0, 0)] * 3 + [(0, w - m.latent_dim)]
+    q = jnp.pad(jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], -1), pad)
+    e = jnp.pad(jnp.concatenate([c[:, :, None], k_pe.astype(c.dtype)], -1),
+                pad)
+    return q, e
+
+
+def mla_values(cfg, params, o_lat: jax.Array) -> jax.Array:
+    """Attention over the latents, (B, S, H, kv_lora_rank), read out as
+    each head's value, (B, S, H, v_head_dim)."""
+    wv = params["wkv_b"][..., cfg.mla.qk_nope_head_dim:].astype(o_lat.dtype)
+    return jnp.einsum("bshr,rhk->bshk", o_lat, wv)
 
 
 def cross_attention_table(cfg, d_model: int | None = None):
@@ -117,23 +208,27 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       softcap: float = 0.0,
                       window: int = 0,
                       chunk: int = 1024,
+                      scale: float | None = None,
                       return_residuals: bool = False):
     """Online-softmax attention, scanning KV in chunks.
 
     Args:
-      q: (B, Sq, H, D); k/v: (B, Skv, K, D) with H % K == 0 (GQA).
+      q: (B, Sq, H, D); k: (B, Skv, K, D), v: (B, Skv, K, Dv) with
+        H % K == 0 (GQA).
+      scale: the softmax scale (default 1 / sqrt(D)).
       q_positions: (B, Sq) absolute positions (defaults to arange).
       kv_positions: (B, Skv) or (Skv,) absolute positions of cache slots.
       kv_len: (B,) valid cache length per sequence (decode masking).
       return_residuals: also return (m, l) LSE stats for distributed merge.
 
     Returns:
-      out (B, Sq, H, D) [, AttnResiduals].
+      out (B, Sq, H, Dv) [, AttnResiduals].
     """
     B, Sq, H, D = q.shape
     _, Skv, K, _ = k.shape
+    Dv = v.shape[-1]
     G = H // K
-    scale_ = 1.0 / math.sqrt(D)
+    scale_ = 1.0 / math.sqrt(D) if scale is None else scale
     if q_positions is None:
         q_positions = jnp.broadcast_to(jnp.arange(Sq, dtype=jnp.int32), (B, Sq))
     if kv_positions is None:
@@ -185,11 +280,11 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     m0 = jnp.full((B, K, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, K, G, Sq), jnp.float32)
-    a0 = jnp.zeros((B, K, G, Sq, D), jnp.float32)
+    a0 = jnp.zeros((B, K, G, Sq, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
                                   jnp.arange(n_chunks, dtype=jnp.int32))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    out = out.reshape(B, K * G, Sq, D).transpose(0, 2, 1, 3).astype(q.dtype)
+    out = out.reshape(B, K * G, Sq, Dv).transpose(0, 2, 1, 3).astype(q.dtype)
     if return_residuals:
         res = AttnResiduals(out=out,
                             m=m.reshape(B, H, Sq), l=l.reshape(B, H, Sq))

@@ -15,29 +15,43 @@ shard_map, so all strategies share identical expert assignments:
 
 DeepSeekMoE extensions: shared experts (always-on, fused into one SwiGLU) and
 ``first_k_dense`` leading dense layers are handled in the block, not here.
+
+A layer told which experts it holds (``MoEConfig.held``: its share under
+expert parallelism) serves through ``moe_held`` instead: no capacity, so
+no token is dropped and a token's output does not depend on which other
+tokens share its chunk or batch.  It computes the held experts' share of
+every token with one grouped SwiGLU (``kernels/moe_expert_ffn``).
 """
 from __future__ import annotations
 
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import constrain, current_mesh, current_rules
-from repro.models.layers.module import weight
+from repro.models.layers.module import bias, weight
 
 
-def moe_table(d_model: int, num_experts: int, d_ff_expert: int):
-    """Router + stacked expert SwiGLU weights (expert dim sharded for EP)."""
+def moe_table(d_model: int, num_experts: int, d_ff_expert: int, *,
+              held: int | None = None, router_bias: bool = False):
+    """Router + stacked expert SwiGLU weights (expert dim sharded for EP).
+    The router scores all ``num_experts``; the stacks hold ``held`` of
+    them (all by default)."""
     e, d, f = num_experts, d_model, d_ff_expert
-    return {
+    h = e if held is None else held
+    t = {
         "router": weight((d, e), ("embed", None), stddev=0.02),
-        "w_gate": weight((e, d, f), ("experts", "embed", "ff_expert")),
-        "w_up": weight((e, d, f), ("experts", "embed", "ff_expert")),
-        "w_down": weight((e, f, d), ("experts", "ff_expert", "embed")),
+        "w_gate": weight((h, d, f), ("experts", "embed", "ff_expert")),
+        "w_up": weight((h, d, f), ("experts", "embed", "ff_expert")),
+        "w_down": weight((h, f, d), ("experts", "ff_expert", "embed")),
     }
+    if router_bias:
+        t["router_bias"] = bias((e,), (None,))
+    return t
 
 
 def route(cfg_moe, params, x: jax.Array):
@@ -52,10 +66,24 @@ def route(cfg_moe, params, x: jax.Array):
     e = cfg_moe.num_experts
     logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
                         params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    prob, idx = jax.lax.top_k(probs, cfg_moe.top_k)
+    if cfg_moe.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    elif cfg_moe.scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {cfg_moe.scoring!r}")
+    if cfg_moe.router_bias:
+        # the correction bias steers which experts are chosen, not how
+        # much each chosen one weighs
+        choose = probs + params["router_bias"].astype(jnp.float32)
+        _, idx = jax.lax.top_k(choose, cfg_moe.top_k)
+        prob = jnp.take_along_axis(probs, idx, axis=-1)
+    else:
+        prob, idx = jax.lax.top_k(probs, cfg_moe.top_k)
     if cfg_moe.norm_topk_prob:
         prob = prob / jnp.maximum(jnp.sum(prob, axis=-1, keepdims=True), 1e-9)
+    if cfg_moe.routed_scaling != 1.0:
+        prob = prob * cfg_moe.routed_scaling
     # aux = E * mean_e( frac_tokens(e) * mean_prob(e) )  (Switch eq. 4)
     one_hot = jax.nn.one_hot(idx, e, dtype=jnp.float32)       # (B,S,k,E)
     frac = jnp.mean(jnp.sum(one_hot, axis=2), axis=(0, 1))    # (E,)
@@ -119,6 +147,94 @@ def moe_einsum(cfg_moe, params, x, idx, prob, *, capacity: int | None = None):
     comb = jnp.sum(disp * prob[..., None, None].astype(x.dtype), axis=2)
     out = jnp.einsum("bsec,ebcd->bsd", comb, ys)
     return constrain(out, "batch", "seq", "embed_act")
+
+
+# ---------------------------------------------------------------------------
+# the held experts' share, without a capacity
+# ---------------------------------------------------------------------------
+
+# tokens per grouped call: the kernel's VMEM holds one group of at most
+# this many rows
+HELD_TOKENS = 256
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+class Stacked(NamedTuple):
+    """One layer's expert weights left inside the stack of every layer's,
+    ``stack[layer]``: the grouped kernel reads that layer in place, where a
+    slice taken before the call would first be copied whole."""
+    stack: jax.Array
+    layer: jax.Array
+
+
+def _row_tile(tokens: int, k: int, experts: int) -> int:
+    """Rows per kernel tile: the least power of two, 16 or more, at least
+    the mean rows an expert gets (at most 128)."""
+    mean = -(-tokens * k // experts)
+    tm = 16
+    while tm < mean and tm < 128:
+        tm *= 2
+    return tm
+
+
+def _held_share(cfg_moe, params, x, idx, prob):
+    """x: (T, D); idx/prob: (T, k).  Returns the held experts' weighted
+    share (T, D) and (rows computed, held experts with a row)."""
+    from repro.kernels.moe_expert_ffn.kernel import group_offsets, rows_bound
+    from repro.kernels.moe_expert_ffn.ops import moe_expert_ffn
+    T, D = x.shape
+    k = cfg_moe.top_k
+    first, count = cfg_moe.held
+    tm = _row_tile(T, k, cfg_moe.num_experts)
+    M = rows_bound(T, k, count, tm)
+    local = idx.reshape(T * k) - first
+    held = (local >= 0) & (local < count)
+    group = jnp.where(held, local, count)                 # count: not held
+    sizes_all = jnp.bincount(group, length=count + 1)
+    sizes = sizes_all[:count].astype(jnp.int32)
+    # rank of each token-choice within its group, in token order
+    order = jnp.argsort(group, stable=True)
+    starts = jnp.cumsum(sizes_all) - sizes_all
+    rank_sorted = jnp.arange(T * k, dtype=jnp.int32) - starts[group[order]]
+    rank = jnp.zeros((T * k,), jnp.int32).at[order].set(rank_sorted)
+    offsets = group_offsets(sizes, tm)
+    dest = jnp.where(held, offsets[jnp.clip(local, 0, count - 1)] + rank, M)
+    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    xs = jnp.zeros((M, D), x.dtype).at[dest].set(x[tok], mode="drop")
+    ws = [params[n] for n in EXPERT_STACKS]
+    layer = ws[0].layer if isinstance(ws[0], Stacked) else None
+    ws = [w.stack if isinstance(w, Stacked) else w for w in ws]
+    ys = moe_expert_ffn(xs, *ws, sizes, layer=layer, tm=tm, group_rows=T)
+    # rows outside the groups are unwritten: select, never multiply, them
+    got = jnp.where(held[:, None], ys[jnp.minimum(dest, M - 1)], 0)
+    y = jnp.sum((got.astype(jnp.float32)
+                 * prob.reshape(T * k, 1)).reshape(T, k, D), axis=1)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y.astype(x.dtype), counts
+
+
+def moe_held(cfg_moe, params, x, idx, prob):
+    """The held experts' share of every token, dropless.
+
+    x: (B, S, D); idx/prob: (B, S, k) from :func:`route` over all
+    ``num_experts``; the expert stacks are arrays or :class:`Stacked`.  The token-choices routed to held experts are sorted
+    into one group per expert and run through one grouped SwiGLU (at most
+    :data:`HELD_TOKENS` tokens per call); the others contribute nothing
+    here (another chip holds them).  Returns (out (B, S, D), counts) with
+    counts int32 (2,): token-choices the held experts computed, and held
+    experts that computed a row.
+    """
+    B, S, D = x.shape
+    T, k = B * S, cfg_moe.top_k
+    xf, idf, pf = x.reshape(T, D), idx.reshape(T, k), prob.reshape(T, k)
+    outs, counts = [], jnp.zeros((2,), jnp.int32)
+    for t0 in range(0, T, HELD_TOKENS):
+        sl = slice(t0, min(t0 + HELD_TOKENS, T))
+        y, c = _held_share(cfg_moe, params, xf[sl], idf[sl], pf[sl])
+        outs.append(y)
+        counts = counts + c
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+    return out.reshape(B, S, D), counts
 
 
 # ---------------------------------------------------------------------------

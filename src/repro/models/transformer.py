@@ -65,12 +65,19 @@ class PagedKVCache(NamedTuple):
     (block_size, D) tile, the unit the Pallas kernels DMA; block 0 is the
     trash block that retired slots write into; block_tables: (B,
     max_blocks) physical block id per logical block, 0 where unassigned;
-    length: (B,) valid KV rows.
+    length: (B,) valid KV rows.  Latent attention keeps one entry per
+    token and layer in ``k`` (K = 1, D the stored entry width) and no
+    ``v``.  ``moe``: with held experts, int32 (2, 2) running totals of the
+    step programs' routed work, wrapping modulo 2**32: row 0 the decode
+    programs', row 1 the prefill and verify programs'; columns the
+    token-choices the held experts computed and the held experts with a
+    row, per grouped call.  None otherwise.
     """
     k: jax.Array
-    v: jax.Array
+    v: jax.Array | None
     block_tables: jax.Array
     length: jax.Array
+    moe: jax.Array | None = None
 
     @property
     def block_size(self) -> int:
@@ -85,13 +92,14 @@ class PagedKVCache(NamedTuple):
 class QuantPagedKVCache(NamedTuple):
     """int8 variant of :class:`PagedKVCache`: pools are int8 with absmax
     scales per (block, kv-head, row).  k/v: (L, N, K, bs, D) int8;
-    k_scale/v_scale: (L, N, K, bs) f32."""
+    k_scale/v_scale: (L, N, K, bs) f32.  ``moe`` as in PagedKVCache."""
     k: jax.Array
     v: jax.Array
     k_scale: jax.Array
     v_scale: jax.Array
     block_tables: jax.Array
     length: jax.Array
+    moe: jax.Array | None = None
 
     @property
     def block_size(self) -> int:
@@ -118,6 +126,9 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype=jnp.bfloat16):
 def make_cache(cfg, batch: int, max_len: int, dtype="bfloat16",
                num_layers: int | None = None,
                length: jax.Array | None = None):
+    if cfg.mla is not None:
+        raise NotImplementedError("latent attention serves from the paged "
+                                  "pool only")
     L = num_layers if num_layers is not None else cfg.num_layers
     hd = cfg.resolved_head_dim
     shape = (L, batch, max_len, cfg.num_kv_heads, hd)
@@ -135,21 +146,28 @@ def make_paged_cache(cfg, num_blocks: int, block_size: int, batch: int,
                      max_blocks: int, dtype="bfloat16",
                      num_layers: int | None = None):
     """Paged cache sized to ``num_blocks`` pool blocks (incl. trash block 0)
-    with ``batch`` block tables of ``max_blocks`` entries each."""
+    with ``batch`` block tables of ``max_blocks`` entries each.  Latent
+    attention has no int8 pool (ServingEngine refuses one when built)."""
     L = num_layers if num_layers is not None else cfg.num_layers
-    hd = cfg.resolved_head_dim
-    shape = (L, num_blocks, cfg.num_kv_heads, block_size, hd)
     tables = jnp.zeros((batch, max_blocks), jnp.int32)
     ln = jnp.zeros((batch,), jnp.int32)
+    moe = (jnp.zeros((2, 2), jnp.int32)
+           if cfg.moe is not None and cfg.moe.held else None)
+    if cfg.mla is not None:
+        shape = (L, num_blocks, 1, block_size, A.mla_entry_dim(cfg))
+        return PagedKVCache(k=jnp.zeros(shape, dtype_of(dtype)), v=None,
+                            block_tables=tables, length=ln, moe=moe)
+    hd = cfg.resolved_head_dim
+    shape = (L, num_blocks, cfg.num_kv_heads, block_size, hd)
     if dtype == "int8":
         return QuantPagedKVCache(
             k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
             k_scale=jnp.zeros(shape[:-1], jnp.float32),
             v_scale=jnp.zeros(shape[:-1], jnp.float32),
-            block_tables=tables, length=ln)
+            block_tables=tables, length=ln, moe=moe)
     dt = dtype_of(dtype)
     return PagedKVCache(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt),
-                        block_tables=tables, length=ln)
+                        block_tables=tables, length=ln, moe=moe)
 
 
 def scatter_prefill_blocks(cache, dense: KVCache, ids: jax.Array):
@@ -185,7 +203,9 @@ def _ffn_table(cfg):
     if cfg.moe is None:
         return {"mlp": swiglu_table(cfg.d_model, cfg.d_ff)}
     m = cfg.moe
-    t = {"moe": MOE.moe_table(cfg.d_model, m.num_experts, m.d_ff_expert)}
+    t = {"moe": MOE.moe_table(cfg.d_model, m.num_experts, m.d_ff_expert,
+                              held=m.held_count,
+                              router_bias=m.router_bias)}
     if m.num_shared_experts:
         t["shared"] = swiglu_table(cfg.d_model,
                                    m.num_shared_experts * m.d_ff_shared)
@@ -229,19 +249,25 @@ def init(cfg, key: jax.Array):
 # ---------------------------------------------------------------------------
 
 def _ffn_apply(cfg, p, h):
-    """FFN half of a block. Returns (out, aux_loss)."""
+    """FFN half of a block. Returns (out, aux_loss, counts): counts is
+    int32 (2,), the held experts' routed work (``moe.moe_held``), zero
+    for every other FFN."""
+    counts = jnp.zeros((2,), jnp.int32)
     if cfg.moe is None or "moe" not in p:
-        return swiglu(p["mlp"], h), jnp.zeros((), jnp.float32)
+        return swiglu(p["mlp"], h), jnp.zeros((), jnp.float32), counts
     m = cfg.moe
     idx, prob, aux = MOE.route(m, p["moe"], h)
-    out = MOE.moe_apply(m, p["moe"], h, idx, prob)
+    if m.held:
+        out, counts = MOE.moe_held(m, p["moe"], h, idx, prob)
+    else:
+        out = MOE.moe_apply(m, p["moe"], h, idx, prob)
     if m.num_shared_experts:
         out = out + swiglu(p["shared"], h)
-    return out, aux
+    return out, aux, counts
 
 
 def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
-                  block_tables, length, chunk):
+                  block_tables, length, chunk, attn_kw=None):
     """Paged decode attention for one layer: write the new KV row into the
     block-table-addressed pool slot, then attend over live blocks only.
 
@@ -249,7 +275,9 @@ def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
     layer's slice of the global pool; block_tables: (B, max_blocks);
     length: (B,) rows already valid (the new row is written at ``length``).
     Retired slots have all-zero tables, so their writes land in the trash
-    block and never corrupt blocks reused by live requests.
+    block and never corrupt blocks reused by live requests.  ``v_new`` and
+    ``pool_v`` None: a latent pool, values read from the key rows
+    (``attn_kw`` gives the kernel their width and the softmax scale).
     """
     from repro.kernels.decode_attention.ops import paged_decode_attention
     N, K, bs, D = pool_k.shape
@@ -258,7 +286,8 @@ def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
     bi = jnp.clip(length // bs, 0, mb - 1)
     bt = block_tables[jnp.arange(B), bi]            # physical block per seq
     off = length % bs
-    row_k, row_v = k_new[:, 0], v_new[:, 0]
+    row_k = k_new[:, 0]
+    row_v = None if v_new is None else v_new[:, 0]
     if scales is not None:
         k_scale, v_scale = scales
         kq, ks = quantize_kv(row_k)
@@ -273,6 +302,10 @@ def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
             chunk=chunk)
         return out[:, None], (nk, nv, nks, nvs)
     nk = pool_k.at[bt, :, off].set(row_k.astype(pool_k.dtype))
+    if pool_v is None:
+        out = paged_decode_attention(q[:, 0], nk, None, block_tables,
+                                     length + 1, chunk=chunk, **attn_kw)
+        return out[:, None], (nk,)
     nv = pool_v.at[bt, :, off].set(row_v.astype(pool_v.dtype))
     out = paged_decode_attention(q[:, 0], nk, nv, block_tables, length + 1,
                                  softcap=cfg.attn_logit_softcap, chunk=chunk)
@@ -280,7 +313,8 @@ def _paged_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
 
 
 def _paged_prefill_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
-                          write_ids, table, q_start, kv_len, chunk):
+                          write_ids, table, q_start, kv_len, chunk,
+                          attn_kw=None):
     """Paged prefill for one layer: write the chunk's KV rows directly
     into pool blocks, then attend causally over the table's blocks.
 
@@ -300,6 +334,8 @@ def _paged_prefill_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
     ``table`` — row ``q_start + j`` lands at block ``table[b, pos // bs]``
     offset ``pos % bs``.  Padding sequences carry all-trash tables, so
     their rows (and any duplicate trash hits) are harmless garbage.
+
+    ``v_new`` and ``pool_v`` None: a latent pool, as in :func:`_paged_attend`.
     """
     from repro.kernels.prefill_attention.ops import paged_prefill_attention
     N, K, bs, D = pool_k.shape
@@ -324,12 +360,21 @@ def _paged_prefill_attend(cfg, q, k_new, v_new, pool_k, pool_v, scales,
                 softcap=cfg.attn_logit_softcap, chunk=chunk)
             return out, (nk, nv, nks, nvs)
         nk = pool_k.at[bt, :, off].set(k_new.astype(pool_k.dtype))
+        if pool_v is None:
+            out = paged_prefill_attention(q, nk, None, table, q_start,
+                                          kv_len, chunk=chunk, **attn_kw)
+            return out, (nk,)
         nv = pool_v.at[bt, :, off].set(v_new.astype(pool_v.dtype))
         out = paged_prefill_attention(q, nk, nv, table, q_start, kv_len,
                                       softcap=cfg.attn_logit_softcap,
                                       chunk=chunk)
         return out, (nk, nv)
     kb = k_new[0].reshape(C // bs, bs, K, D).swapaxes(1, 2)
+    if pool_v is None:
+        nk = pool_k.at[write_ids].set(kb.astype(pool_k.dtype))
+        out = paged_prefill_attention(q, nk, None, table, q_start, kv_len,
+                                      chunk=chunk, **attn_kw)
+        return out, (nk,)
     vb = v_new[0].reshape(C // bs, bs, K, D).swapaxes(1, 2)
     if scales is not None:
         k_scale, v_scale = scales
@@ -371,7 +416,12 @@ def block_apply(cfg, p, x, positions, *,
     # full sequence, so the gather happens here (post-norm, bf16).
     h = constrain(h, "batch", "seq", "embed_act")
     pos1d = positions[0] if cfg.m_rope else positions
-    if cache_k is None:
+    if cfg.mla is not None:
+        attn, new_kv = _mla_block_attention(
+            cfg, p["attn"], h, positions, cache_k=cache_k, kv_len=kv_len,
+            block_tables=block_tables, paged_prefill=paged_prefill,
+            chunk=chunk)
+    elif cache_k is None:
         q, k, v = A.qkv_project(cfg, p["attn"], h, positions)
         attn = A.chunked_attention(
             q, k, v, causal=True, q_positions=pos1d, kv_positions=pos1d,
@@ -398,16 +448,42 @@ def block_apply(cfg, p, x, positions, *,
         new_kv = tuple(new_kv)
     attn = A.attn_output(cfg, p["attn"], attn)
     if cfg.parallel_block:
-        ffn, aux = _ffn_apply(cfg, p, h)
+        ffn, aux, counts = _ffn_apply(cfg, p, h)
         x = x + attn + ffn
     else:
         x = x + attn
         h2 = apply_norm(cfg, p["ln2"], x)
-        ffn, aux = _ffn_apply(cfg, p, h2)
+        ffn, aux, counts = _ffn_apply(cfg, p, h2)
         x = x + ffn
     # carry leaves the block sequence-sharded (training SP; no-op otherwise)
     x = constrain(x, "batch", "seq_sp", "embed_act")
-    return x, aux, new_kv
+    return x, aux, new_kv, counts
+
+
+def _mla_block_attention(cfg, p, h, positions, *, cache_k, kv_len,
+                         block_tables, paged_prefill, chunk):
+    """Latent attention of one block.  Without a cache, the published
+    form over the whole sequence.  Against the paged latent pool, the
+    absorbed form: each token's entry ``[c ; k_pe]`` is written into the
+    pool, the queries (taken into the latent space) attend over the
+    entries, and the attended latents are read out as the heads' values.
+    Returns (values (B, S, H, v_head_dim), new pool tuple or None)."""
+    if cache_k is None:
+        return A.mla_attention(cfg, p, h, positions, chunk=chunk), None
+    if block_tables is None:
+        raise NotImplementedError("latent attention serves from the paged "
+                                  "pool only")
+    q, e = A.mla_absorb(cfg, p, *A.mla_project(cfg, p, h, positions))
+    kw = dict(scale=A.mla_scale(cfg), v_dim=cfg.mla.kv_lora_rank)
+    if paged_prefill is not None:
+        o_lat, new_kv = _paged_prefill_attend(
+            cfg, q, e, None, cache_k, None, None, chunk=chunk, attn_kw=kw,
+            **paged_prefill)
+    else:
+        o_lat, new_kv = _paged_attend(cfg, q, e, None, cache_k, None, None,
+                                      block_tables, kv_len, chunk,
+                                      attn_kw=kw)
+    return A.mla_values(cfg, p, o_lat), new_kv
 
 
 _REMAT_POLICIES = {
@@ -419,7 +495,8 @@ _REMAT_POLICIES = {
 
 def _scan_blocks(cfg, stacked, x, positions, *, remat, cache=None,
                  collect_kv=False, paged_prefill=None, chunk=1024):
-    """Scan the homogeneous block stack. Returns (x, aux_sum, (ks, vs)).
+    """Scan the homogeneous block stack. Returns (x, aux_sum, (ks, vs),
+    the held experts' routed work summed over the layers).
 
     ``collect_kv`` stacks each layer's fresh K/V as scan outputs (prefill);
     training leaves it off so no (L, B, S, K, D) buffer is ever requested.
@@ -429,39 +506,98 @@ def _scan_blocks(cfg, stacked, x, positions, *, remat, cache=None,
     tables = getattr(cache, "block_tables", None)
 
     def body_nocache(carry, p):
-        h, aux = carry
-        h, a, kv = block_apply(cfg, p, h, positions, chunk=chunk)
+        h, aux, n = carry
+        h, a, kv, c = block_apply(cfg, p, h, positions, chunk=chunk)
         ys = kv if collect_kv else None
-        return (h, aux + a), ys
+        return (h, aux + a, n + c), ys
 
     def body_cache(carry, layer):
-        h, aux = carry
+        h, aux, n = carry
         if quant:
             p, ck, cv, ks, vs = layer
             scales = (ks, vs)
         else:
             p, ck, cv = layer
             scales = None
-        h, a, kv = block_apply(cfg, p, h, positions,
-                               cache_k=ck, cache_v=cv, cache_scales=scales,
-                               kv_len=cache.length, block_tables=tables,
-                               paged_prefill=paged_prefill, chunk=chunk)
-        return (h, aux + a), kv
+        h, a, kv, c = block_apply(cfg, p, h, positions,
+                                  cache_k=ck, cache_v=cv,
+                                  cache_scales=scales, kv_len=cache.length,
+                                  block_tables=tables,
+                                  paged_prefill=paged_prefill, chunk=chunk)
+        return (h, aux + a, n + c), kv
 
     body = body_cache if cache is not None else body_nocache
     if remat and cfg.remat != "none":
         policy = _REMAT_POLICIES.get(cfg.remat, _REMAT_POLICIES["full"])
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
 
-    carry0 = (x, jnp.zeros((), jnp.float32))
+    carry0 = (x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32))
     if cache is None:
-        (x, aux), ys = jax.lax.scan(body, carry0, stacked)
+        (x, aux, counts), ys = jax.lax.scan(body, carry0, stacked)
         kv = ys if collect_kv else None
     else:
         xs = ((stacked, cache.k, cache.v, cache.k_scale, cache.v_scale)
               if quant else (stacked, cache.k, cache.v))
-        (x, aux), kv = jax.lax.scan(body, carry0, xs)
-    return x, aux, kv
+        (x, aux, counts), kv = jax.lax.scan(body, carry0, xs)
+    return x, aux, kv, counts
+
+
+_POOL_FIELDS = ("k", "v", "k_scale", "v_scale")
+
+
+def _pools(cache) -> tuple:
+    """A paged cache's pool arrays, in field order, those it has."""
+    return tuple(getattr(cache, f) for f in _POOL_FIELDS
+                 if getattr(cache, f, None) is not None)
+
+
+def _layer_pools(layer: list):
+    """One layer's pools, as :func:`_pools` orders them, as block_apply
+    takes them: (cache_k, cache_v, cache_scales)."""
+    if len(layer) == 4:
+        return layer[0], layer[1], (layer[2], layer[3])
+    return layer[0], (layer[1] if len(layer) > 1 else None), None
+
+
+def _scan_pooled(cfg, stacked, x, positions, cache, pools, layer0, *,
+                 paged_prefill=None, chunk=1024):
+    """Scan the block stack over paged pools carried whole: stacked layer
+    ``i`` reads and writes pool layer ``layer0 + i`` in place.  Returns
+    (x, aux_sum, new pools, routed-work counts)."""
+
+    # the held experts' stacks stay whole: the grouped kernel reads layer
+    # i in place, where a scan would copy each layer's slice out first
+    moe = stacked.get("moe") if cfg.moe is not None and cfg.moe.held else None
+    if moe is not None:
+        whole = {w: moe[w] for w in MOE.EXPERT_STACKS}
+        stacked = dict(stacked, moe={k: v for k, v in moe.items()
+                                     if k not in whole})
+
+    def body(carry, layer):
+        h, aux, n, pools = carry
+        p, i = layer
+        if moe is not None:
+            p = dict(p, moe=dict(p["moe"], **{
+                w: MOE.Stacked(a, i) for w, a in whole.items()}))
+        at = layer0 + i
+        ck, cv, scales = _layer_pools(
+            [jax.lax.dynamic_index_in_dim(P, at, keepdims=False)
+             for P in pools])
+        h, a, kv, c = block_apply(cfg, p, h, positions, cache_k=ck,
+                                  cache_v=cv, cache_scales=scales,
+                                  kv_len=cache.length,
+                                  block_tables=cache.block_tables,
+                                  paged_prefill=paged_prefill, chunk=chunk)
+        pools = tuple(jax.lax.dynamic_update_index_in_dim(P, w, at, 0)
+                      for P, w in zip(pools, kv))
+        return (h, aux + a, n + c, pools), None
+
+    n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+    carry0 = (x, jnp.zeros((), jnp.float32), jnp.zeros((2,), jnp.int32),
+              pools)
+    (x, aux, counts, pools), _ = jax.lax.scan(
+        body, carry0, (stacked, jnp.arange(n, dtype=jnp.int32)))
+    return x, aux, pools, counts
 
 
 def _apply_backbone(cfg, params, tokens, positions, *, remat,
@@ -470,38 +606,59 @@ def _apply_backbone(cfg, params, tokens, positions, *, remat,
     compute_dt = dtype_of(cfg.compute_dtype)
     x = embed(params["embed"], tokens, compute_dt)
     aux_total = jnp.zeros((), jnp.float32)
+    counts = jnp.zeros((2,), jnp.int32)
     quant = isinstance(cache, (QuantKVCache, QuantPagedKVCache))
     paged = isinstance(cache, (PagedKVCache, QuantPagedKVCache))
     dense_caches = []
     n_dense = len(params.get("dense_blocks", ()))
+    # a dense prefix over one paged pool: the pools are carried whole and
+    # each layer writes its own slice in place (slicing the prefix off and
+    # concatenating it back would copy every pool twice per step)
+    pools = (_pools(cache) if paged and n_dense else None)
     for i, bp in enumerate(params.get("dense_blocks", ())):
         ck = cv = scales = tables = None
         kl = None
-        if cache is not None:
+        if pools is not None:
+            ck, cv, scales = _layer_pools([P[i] for P in pools])
+            kl, tables = cache.length, cache.block_tables
+        elif cache is not None:
             ck, cv, kl = cache.k[i], cache.v[i], cache.length
             if quant:
                 scales = (cache.k_scale[i], cache.v_scale[i])
             if paged:
                 tables = cache.block_tables
-        x, a, kv = block_apply(cfg, bp, x, positions,
-                               cache_k=ck, cache_v=cv, cache_scales=scales,
-                               kv_len=kl, block_tables=tables,
-                               paged_prefill=paged_prefill, chunk=chunk)
+        x, a, kv, c = block_apply(cfg, bp, x, positions,
+                                  cache_k=ck, cache_v=cv,
+                                  cache_scales=scales, kv_len=kl,
+                                  block_tables=tables,
+                                  paged_prefill=paged_prefill, chunk=chunk)
         aux_total += a
-        if cache is not None or collect_kv:
+        counts += c
+        if pools is not None:
+            pools = tuple(P.at[i].set(n) for P, n in zip(pools, kv))
+        elif cache is not None or collect_kv:
             dense_caches.append(kv)
-    sub = None
-    if cache is not None:
-        # slice off the unrolled dense layers; only the stacked pools /
-        # caches have a leading layer axis (block_tables and length don't)
-        sub = jax.tree_util.tree_map(
-            lambda c: c[n_dense:] if c.ndim > 2 else c, cache)
-        sub = sub._replace(length=cache.length)
-    x, aux, kv = _scan_blocks(cfg, params["blocks"], x, positions,
-                              remat=remat, cache=sub,
-                              collect_kv=collect_kv,
-                              paged_prefill=paged_prefill, chunk=chunk)
+    if pools is not None:
+        x, aux, kv, c = _scan_pooled(cfg, params["blocks"], x, positions,
+                                     cache, pools, n_dense,
+                                     paged_prefill=paged_prefill,
+                                     chunk=chunk)
+    else:
+        sub = None
+        if cache is not None:
+            # slice off the unrolled dense layers; only the stacked pools /
+            # caches have a leading layer axis (block_tables and length
+            # don't)
+            sub = jax.tree_util.tree_map(
+                lambda c: c[n_dense:] if c.ndim > 2 else c, cache)
+            sub = sub._replace(length=cache.length)
+        x, aux, kv, c = _scan_blocks(cfg, params["blocks"], x, positions,
+                                     remat=remat, cache=sub,
+                                     collect_kv=collect_kv,
+                                     paged_prefill=paged_prefill,
+                                     chunk=chunk)
     aux_total += aux
+    counts += c
     x = apply_norm(cfg, params["ln_f"], x)
     new_cache = None
     if kv is not None:
@@ -514,14 +671,16 @@ def _apply_backbone(cfg, params, tokens, positions, *, remat,
                   else jnp.full((tokens.shape[0],), tokens.shape[1],
                                 jnp.int32))
         if paged:
+            moe = (None if cache.moe is None else
+                   cache.moe.at[int(paged_prefill is not None)].add(counts))
             if len(kv) == 4:
                 new_cache = QuantPagedKVCache(
                     k=kv[0], v=kv[1], k_scale=kv[2], v_scale=kv[3],
-                    block_tables=cache.block_tables, length=length)
+                    block_tables=cache.block_tables, length=length, moe=moe)
             else:
-                new_cache = PagedKVCache(k=kv[0], v=kv[1],
-                                         block_tables=cache.block_tables,
-                                         length=length)
+                new_cache = PagedKVCache(
+                    k=kv[0], v=kv[1] if len(kv) > 1 else None,
+                    block_tables=cache.block_tables, length=length, moe=moe)
         elif len(kv) == 4:
             new_cache = QuantKVCache(k=kv[0], v=kv[1], k_scale=kv[2],
                                      v_scale=kv[3], length=length)
@@ -562,6 +721,9 @@ def prefill(cfg, params, tokens, positions=None, *, cache_dtype="bfloat16",
     bucket, so the real last token sits at ``prompt_len - 1`` (causality
     keeps its logits independent of the padding that follows).
     """
+    if cfg.mla is not None:
+        raise NotImplementedError("latent attention prefills into the paged "
+                                  "pool only (prefill_paged)")
     if positions is None:
         positions = default_positions(cfg, tokens)
     x, _, cache = _apply_backbone(cfg, params, tokens, positions, remat=False,

@@ -126,6 +126,10 @@ MERGE_RULES: dict[str, str] = {
     "admissions": "sum",
     "decode_pages_live": "sum",
     "decode_pages_table": "sum",
+    "moe_rows": "sum",
+    "moe_expert_hits": "sum",
+    "moe_rows_prefill": "sum",
+    "moe_expert_hits_prefill": "sum",
     "ttft": "extend",
     "tpot": "extend",
     "decode_gaps": "extend",
@@ -197,6 +201,13 @@ class ServeStats:
                                         # decode kernel reads, sum over slots
                                         # of cdiv(length + 1, block size)
     decode_pages_table: int = 0         # ... and slots x table width
+    moe_rows: int = 0                   # held experts: token-choices the
+                                        # held experts computed, summed over
+                                        # layers and step programs
+    moe_expert_hits: int = 0            # ... held (layer, expert) pairs with
+                                        # a row, summed over grouped calls
+    moe_rows_prefill: int = 0           # ... of them, the prefill and
+    moe_expert_hits_prefill: int = 0    # verify programs' (the rest decode's)
     ttft: list = field(default_factory=list)    # per-request seconds
     tpot: list = field(default_factory=list)    # per-request seconds/token
     decode_gaps: list = field(default_factory=list)  # s between decode steps
@@ -336,6 +347,10 @@ class WindowBase(NamedTuple):
     admissions: int = 0
     decode_pages_live: int = 0  # decode kernel reads (paged)
     decode_pages_table: int = 0
+    moe_rows: int = 0           # held experts' routed work (0 when dense)
+    moe_expert_hits: int = 0
+    moe_rows_prefill: int = 0
+    moe_expert_hits_prefill: int = 0
 
 
 def prefix_digests(tokens: np.ndarray, block_size: int) -> list[bytes]:
@@ -697,6 +712,9 @@ class ServingEngine:
         self._last_decode_end: float | None = None  # owned-by: executor-thread
         self._step_wait_s = 0.0  # owned-by: executor-thread; device waits this step
         self._gaps_dropped = 0  # owned-by: executor-thread; decode_gaps entries trimmed
+        if paged and cfg.mla is not None and cache_dtype == "int8":
+            raise ValueError("latent attention has no int8 pool: serve it "
+                             "with cache_dtype='bfloat16'")
         if paged and getattr(cfg, "sliding_window", 0):
             # the paged attention paths (prefill and decode) are
             # full-causal; serving a sliding-window arch through them
@@ -787,6 +805,8 @@ class ServingEngine:
         self._merge = jax.jit(_merge_slot)
         self._prefill_shapes: set = set()  # owned-by: executor-thread; jitted signatures
         self._state = None                 # owned-by: executor-thread; decode-state pytree
+        self._moe_seen = np.zeros((2, 2), np.uint32)  # guarded-by: self._moe_lock; state.moe at last pull
+        self._moe_lock = threading.Lock()
         self._last: np.ndarray | None = None  # owned-by: executor-thread; (slots, V) logits
         self.totals = ServeStats()           # lifetime counters (monotonic)
         self._thread: threading.Thread | None = None
@@ -1679,7 +1699,30 @@ class ServingEngine:
         self._step_wait_s += t1 - t0
         with TraceAnnotation("engine.logits_to_host", bytes=out.nbytes):
             host = np.asarray(out)
+        self._pull_moe()
         return host, time.perf_counter()
+
+    def _pull_moe(self) -> None:
+        """Add the held experts' routed work since the last pull to the
+        lifetime counters.  The state carries running totals (wrapping
+        modulo 2**32) of every step program dispatched so far.  The
+        executor pulls them after each decode or verify step, a second
+        16-byte copy beside the logits' that waits on nothing (the program
+        that wrote them has finished); a window's snapshot pulls them too,
+        waiting for the programs in flight, so work dispatched before it
+        counts before it.  A state without held experts carries none."""
+        with self._moe_lock:
+            moe = getattr(self._state, "moe", None)
+            if moe is None:
+                return
+            now = np.asarray(moe).astype(np.uint32)
+            (dec_rows, dec_hits), (pre_rows, pre_hits) = (
+                now - self._moe_seen).tolist()
+            self._moe_seen = now
+            self.totals.moe_rows += dec_rows + pre_rows
+            self.totals.moe_expert_hits += dec_hits + pre_hits
+            self.totals.moe_rows_prefill += pre_rows
+            self.totals.moe_expert_hits_prefill += pre_hits
 
     def _step(self) -> bool:
         """One executor iteration: refill free slots, spend the chunked
@@ -1977,6 +2020,7 @@ class ServingEngine:
             self.params, self._put(tokens), self._state,
             self._put(tbl), self._put(qs), self._put(kl))
         logits = np.asarray(logits)              # (slots, C, V)
+        self._pull_moe()
         # 3. vectorized longest-prefix acceptance
         rows = np.array([s for s, _ in spec])
         accepted, _ = greedy_accept_prefix(
@@ -2040,6 +2084,7 @@ class ServingEngine:
         pool/preemption stats out of a live engine."""
         if self.pool is not None:
             self.pool.reset_peak()
+        self._pull_moe()
         return WindowBase(
             tokens=self.totals.tokens, prefills=self.totals.prefills,
             decode_steps=self.totals.decode_steps,
@@ -2067,13 +2112,18 @@ class ServingEngine:
             queue_wait_s=self.scheduler.queue_wait_s,
             admissions=self.scheduler.admissions,
             decode_pages_live=self.totals.decode_pages_live,
-            decode_pages_table=self.totals.decode_pages_table)
+            decode_pages_table=self.totals.decode_pages_table,
+            moe_rows=self.totals.moe_rows,
+            moe_expert_hits=self.totals.moe_expert_hits,
+            moe_rows_prefill=self.totals.moe_rows_prefill,
+            moe_expert_hits_prefill=self.totals.moe_expert_hits_prefill)
 
     def collect_window(self, base: "WindowBase", requests: list[Request],
                        wall_s: float) -> ServeStats:
         """Stats for everything this engine did since ``base`` (a
         :meth:`begin_window` snapshot), with per-request latency metrics
         filled from ``requests``."""
+        self._pull_moe()
         stats = ServeStats(requests=len(requests), wall_s=wall_s)
         stats.tokens = self.totals.tokens - base.tokens
         stats.prefills = self.totals.prefills - base.prefills
@@ -2116,6 +2166,13 @@ class ServingEngine:
                                    - base.decode_pages_live)
         stats.decode_pages_table = (self.totals.decode_pages_table
                                     - base.decode_pages_table)
+        stats.moe_rows = self.totals.moe_rows - base.moe_rows
+        stats.moe_expert_hits = (self.totals.moe_expert_hits
+                                 - base.moe_expert_hits)
+        stats.moe_rows_prefill = (self.totals.moe_rows_prefill
+                                  - base.moe_rows_prefill)
+        stats.moe_expert_hits_prefill = (self.totals.moe_expert_hits_prefill
+                                         - base.moe_expert_hits_prefill)
         if stats.prefix_lookups:
             stats.kv_hit_rate = ((stats.prefix_shared_blocks
                                   + stats.prefix_hits_host)

@@ -6,6 +6,7 @@ import threading
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -135,3 +136,69 @@ def test_decode_pages_follow_the_slots_lengths(smoke):
     assert (merged.decode_pages_live, merged.decode_pages_table) == (
         eng.totals.decode_pages_live, eng.totals.decode_pages_table)
     assert 0 < merged.decode_pages_live < merged.decode_pages_table
+
+
+def test_held_expert_counters_window_merge_and_dense_zero(smoke):
+    """``moe_rows`` / ``moe_expert_hits`` and their prefill parts: a model
+    with held experts counts its routed work in each window, the windows
+    add up to the running totals the step programs keep on the device,
+    and merge by sum; a dense model counts nothing."""
+    import deepseek_tiny as DT
+    cfg = DT.config(dtype="bfloat16")
+    eng = ServingEngine(cfg, DT.params(cfg), max_len=64, batch_slots=2,
+                        prefill_chunk=16)
+    first = eng.serve(_reqs(cfg, [0, 1], n_new=4, size=20))
+    second = eng.serve(_reqs(cfg, [2], n_new=6, size=9))
+    for stats in (first, second):
+        assert 0 < stats.moe_expert_hits <= stats.moe_rows
+    for stats in (first, second):
+        assert 0 < stats.moe_rows_prefill < stats.moe_rows
+        assert 0 < stats.moe_expert_hits_prefill < stats.moe_expert_hits
+    merged = ServeStats().merge_from(first).merge_from(second)
+    (dec_rows, dec_hits), (pre_rows, pre_hits) = np.asarray(
+        eng._state.moe).tolist()
+    assert (merged.moe_rows, merged.moe_expert_hits) == (
+        eng.totals.moe_rows, eng.totals.moe_expert_hits) == (
+        dec_rows + pre_rows, dec_hits + pre_hits)
+    assert (merged.moe_rows_prefill, merged.moe_expert_hits_prefill) == (
+        pre_rows, pre_hits)
+    cfg, params = smoke
+    dense = _engine(cfg, params).serve(_reqs(cfg, [3], n_new=3))
+    assert (dense.moe_rows, dense.moe_expert_hits) == (0, 0)
+
+
+def test_held_expert_counters_land_in_the_window_that_dispatched_them():
+    """A window's snapshot pulls the routed work dispatched before it,
+    though no logits have been read since: a prefill chunk dispatched
+    before ``begin_window`` counts before it and not in the window."""
+    import deepseek_tiny as DT
+    cfg = DT.config(dtype="bfloat16")
+    eng = ServingEngine(cfg, DT.params(cfg), max_len=64, batch_slots=2,
+                        prefill_chunk=16)
+    eng._state = eng._init_state()
+    C = eng.block_size
+    toks = np.asarray(DT.tokens(C, vocab=cfg.vocab_size))
+    _, eng._state = eng._prefill_paged(
+        eng.params, eng._put(toks), eng._state,
+        eng._put(np.zeros((1,), np.int32)), eng._put(np.zeros((1, 1), np.int32)),
+        eng._put(np.asarray([0], np.int32)),
+        eng._put(np.asarray([C], np.int32)), jnp.int32(C - 1))
+    base = eng.begin_window()
+    assert 0 < base.moe_rows_prefill == base.moe_rows
+    window = eng.collect_window(base, [], 0.0)
+    assert (window.moe_rows, window.moe_expert_hits) == (0, 0)
+
+
+def test_held_expert_counters_with_an_int8_pool():
+    """Held experts behind an int8 K/V pool count their routed work as
+    behind a bf16 one: the quantized cache carries the totals too."""
+    import dataclasses
+    import deepseek_tiny as DT
+    from repro.models.transformer import QuantPagedKVCache
+    cfg = dataclasses.replace(DT.config(dtype="bfloat16"), mla=None)
+    eng = ServingEngine(cfg, DT.params(cfg), max_len=64, batch_slots=2,
+                        prefill_chunk=16, cache_dtype="int8")
+    stats = eng.serve(_reqs(cfg, [0, 1], n_new=4, size=20))
+    assert isinstance(eng._state, QuantPagedKVCache)
+    assert 0 < stats.moe_rows_prefill < stats.moe_rows
+    assert 0 < stats.moe_expert_hits_prefill < stats.moe_expert_hits

@@ -1,4 +1,6 @@
 """MoE: routing invariants + dispatch-strategy equivalence."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,3 +86,107 @@ def test_moe_grad_flows(seed):
     g = jax.grad(loss)(params, x)
     gn = sum(float(jnp.abs(l).sum()) for l in jax.tree_util.tree_leaves(g))
     assert np.isfinite(gn) and gn > 0
+
+
+# -- sigmoid routing and the held experts' dropless share ---------------------
+
+def _held_setup(held=(2, 4), T=24, d=64, seed=0):
+    """Routing inputs and one expert layer of the tiny DeepSeek-V3 at
+    float32, its stacks cut to ``held``."""
+    import deepseek_tiny as DT
+    cfg = DT.config(held=(0, 8))
+    full = jax.tree_util.tree_map(lambda a: a[0], DT.params(cfg)["blocks"])
+    x = jax.random.normal(jax.random.PRNGKey(seed + 7), (1, T, d))
+    return cfg.moe, full, x
+
+
+def _cut(cfg_moe, full, held):
+    """The layer as the chip holding experts ``held`` = (first, count)
+    holds it: the router whole, the stacks sliced."""
+    first, n = held
+    moe = {k: (v[first:first + n] if k in M.EXPERT_STACKS else v)
+           for k, v in full["moe"].items()}
+    return dataclasses.replace(cfg_moe, held=held), moe
+
+
+def test_sigmoid_bias_scaled_routing_matches_numpy():
+    """Sigmoid scores; the top k of score + correction bias; weights the
+    chosen scores normalised over all k choices, times the scaling."""
+    cfg_moe, full, x = _held_setup()
+    p = full["moe"]
+    idx, prob, _ = M.route(cfg_moe, p, x)
+    h = np.asarray(x, np.float64)[0]
+    s = 1 / (1 + np.exp(-(h @ np.asarray(p["router"], np.float64))))
+    choose = s + np.asarray(p["router_bias"], np.float64)
+    want = np.argsort(-choose, axis=-1, kind="stable")[:, :3]
+    np.testing.assert_array_equal(np.asarray(idx[0]), want)
+    w = np.take_along_axis(s, want, -1)
+    w = 2.446 * w / w.sum(-1, keepdims=True)
+    # float32 routing against a float64 hand computation
+    np.testing.assert_allclose(np.asarray(prob[0]), w, rtol=1e-5)
+    # the bias changed some choice: without it the top 3 differ somewhere
+    plain = np.argsort(-s, axis=-1, kind="stable")[:, :3]
+    assert (np.sort(plain, -1) != np.sort(want, -1)).any()
+
+
+def test_held_shares_add_up_to_the_uncut_layer():
+    """Four chips holding experts 0-1, 2-3, 4-5 and 6-7: their routed
+    shares, with the shared experts counted once, add up to the layer
+    that holds all eight."""
+    from repro.models import transformer as TR
+    import deepseek_tiny as DT
+    cfg_moe, full, x = _held_setup()
+    cfg = DT.config(held=(0, 8))
+    uncut, _, _ = TR._ffn_apply(cfg, full, x)
+    shared = TR.swiglu(full["shared"], x)
+    total = -3 * shared
+    for first in (0, 2, 4, 6):
+        c, moe = _cut(cfg_moe, full, (first, 2))
+        y, _, _ = TR._ffn_apply(cfg.replace(moe=c), dict(full, moe=moe), x)
+        total = total + y
+    # float32 sums of the same terms in another order
+    np.testing.assert_allclose(total, uncut,
+                               atol=1e-5 * float(jnp.abs(uncut).max()))
+    # and the uncut dropless layer is the every-expert-on-every-token oracle
+    idx, prob, _ = M.route(cfg_moe, full["moe"], x)
+    dense = M.moe_dense(cfg_moe, full["moe"], x, idx, prob) + shared
+    np.testing.assert_allclose(uncut, dense,
+                               atol=1e-5 * float(jnp.abs(dense).max()))
+
+
+def test_held_counts_rows_and_experts():
+    cfg_moe, full, x = _held_setup()
+    c, moe = _cut(cfg_moe, full, (2, 4))
+    idx, prob, _ = M.route(c, moe, x)
+    _, counts = M.moe_held(c, moe, x, idx, prob)
+    local = np.asarray(idx).reshape(-1) - 2
+    mine = local[(local >= 0) & (local < 4)]
+    assert np.asarray(counts).tolist() == [len(mine), len(set(mine))]
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["ref", "interpret"])
+def test_held_token_output_is_independent_of_its_batch_and_chunk(pallas):
+    """No capacity: a token's expert output is the same bits alone, inside
+    a batch of other tokens, and inside chunks of other widths."""
+    from repro.kernels.dispatch import pallas_enabled
+    cfg_moe, full, x = _held_setup(T=40)
+    c, moe = _cut(cfg_moe, full, (2, 4))
+    x = x.astype(jnp.bfloat16)
+    moe = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), moe)
+
+    def share(xs):
+        idx, prob, _ = M.route(c, moe, xs)
+        return M.moe_held(c, moe, xs, idx, prob)[0]
+
+    with pallas_enabled(pallas):
+        whole = share(x)
+        for width in (1, 8, 16):
+            parts = [share(x[:, i:i + width])
+                     for i in range(0, x.shape[1], width)]
+            np.testing.assert_array_equal(jnp.concatenate(parts, 1), whole,
+                                          err_msg=f"chunks of {width}")
+        others = jax.random.normal(jax.random.PRNGKey(99), (1, 200, 64),
+                                   jnp.bfloat16)
+        mixed = share(jnp.concatenate([others[:, :100], x, others[:, 100:]],
+                                      1))
+        np.testing.assert_array_equal(mixed[:, 100:140], whole)

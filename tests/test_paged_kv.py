@@ -550,3 +550,113 @@ def test_paged_engine_int8_cache_top1_stable():
                 for ra, rb_ in zip(rb, rq))
     assert flips <= 1
     assert q8._state.k.dtype == jnp.int8
+
+
+# -- latent pools: one entry per token, values its first lanes ----------------
+
+def _latent_case(seed=0, B=4, H=16, W=128, Dv=96, bs=16, mb=9):
+    """A latent pool (N, 1, bs, W) with shuffled tables and lengths at the
+    kernel's group edges, and G = H query heads against it."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    N = 1 + B * mb
+    pool = jax.random.normal(ks[0], (N, 1, bs, W)).astype(jnp.bfloat16)
+    q = jax.random.normal(ks[1], (B, H, W)).astype(jnp.bfloat16)
+    rng = np.random.default_rng(seed)
+    tables = jnp.asarray((1 + rng.permutation(N - 1)).reshape(B, mb),
+                         jnp.int32)
+    lengths = jnp.asarray([1, bs, 8 * bs + 1, mb * bs][:B], jnp.int32)
+    return q, pool, tables, lengths, Dv
+
+
+def _bf16_tol(pool):
+    """The kernels round the probabilities to bf16 before the value
+    product and the output to bf16: each a relative 2**-8, against values
+    no larger than the pool's largest."""
+    return 2 * 2.0 ** -8 * float(jnp.abs(pool).max())
+
+
+def _plain_latent(q, pool, tables, lengths, Dv, scale, q_pos=None):
+    """Straightforward jnp in float32: gather each sequence's entries,
+    score every query head against every entry, mask past the length
+    (and, for a chunk, after the query's position), read values from the
+    first Dv lanes."""
+    B = tables.shape[0]
+    e = pool[tables][:, :, 0].reshape(B, -1, pool.shape[-1])
+    e = e.astype(jnp.float32)
+    s = jnp.einsum("bqhw,bkw->bqhk", q.astype(jnp.float32), e) * scale
+    k = jnp.arange(e.shape[1])
+    ok = k[None, None, :] < lengths[:, None, None]
+    if q_pos is not None:
+        ok = ok & (k[None, None, :] <= q_pos[:, :, None])
+    s = jnp.where(ok[:, :, None, :], s, -jnp.inf)
+    return jnp.einsum("bqhk,bkv->bqhv", jax.nn.softmax(s, -1), e[..., :Dv])
+
+
+def test_latent_decode_kernel_matches_plain_jnp():
+    q, pool, tables, lengths, Dv = _latent_case()
+    scale = 24 ** -0.5
+    want = _plain_latent(q[:, None], pool, tables, lengths, Dv, scale)[:, 0]
+    out = pallas_paged(q, pool, None, tables, lengths, scale=scale, v_dim=Dv,
+                       interpret=True)
+    ref = paged_decode_attention_ref(q, pool, None, tables, lengths,
+                                     scale=scale, v_dim=Dv)
+    assert out.shape == (4, 16, Dv)
+    tol = _bf16_tol(pool)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=tol)
+    np.testing.assert_allclose(np.asarray(ref, np.float32), want, atol=tol)
+
+
+def test_latent_prefill_kernel_matches_plain_jnp():
+    from repro.kernels.prefill_attention.kernel import \
+        paged_prefill_attention as pallas_prefill
+    from repro.kernels.prefill_attention.ref import \
+        paged_prefill_attention_ref
+    q1, pool, tables, lengths, Dv = _latent_case(seed=1)
+    C, start = 32, jnp.asarray([0, 16, 100, 112], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(5),
+                          (4, C, 16, pool.shape[-1])).astype(jnp.bfloat16)
+    kv_len = jnp.minimum(start + C, tables.shape[1] * 16)
+    scale = 24 ** -0.5
+    q_pos = start[:, None] + jnp.arange(C)[None]
+    want = _plain_latent(q, pool, tables, kv_len, Dv, scale, q_pos)
+    out = pallas_prefill(q, pool, None, tables, start, kv_len, scale=scale,
+                         v_dim=Dv, interpret=True)
+    ref = paged_prefill_attention_ref(q, pool, None, tables, start, kv_len,
+                                      scale=scale, v_dim=Dv)
+    assert out.shape == (4, C, 16, Dv)
+    tol = _bf16_tol(pool)
+    np.testing.assert_allclose(np.asarray(out, np.float32), want, atol=tol)
+    np.testing.assert_allclose(np.asarray(ref, np.float32), want, atol=tol)
+
+
+def test_dense_pools_unchanged_by_the_value_options():
+    """With a value pool and no scale given, the kernel is the dense one:
+    same output bits as an explicit 1/sqrt(D) scale."""
+    q, kp, vp, tables, lengths, _ = _group_case(2, 8, "bfloat16")
+    plain = pallas_paged(q, kp, vp, tables, lengths, interpret=True)
+    given = pallas_paged(q, kp, vp, tables, lengths, interpret=True,
+                         scale=1.0 / (kp.shape[-1] ** 0.5))
+    np.testing.assert_array_equal(plain, given)
+
+
+def test_host_tier_round_trips_a_latent_block():
+    """A latent pool has only ``k``: a block read for the host tier holds
+    that leaf alone, and writing it back into another block reproduces it
+    exactly."""
+    import deepseek_tiny as DT
+    cfg = DT.config(dtype="bfloat16")
+    params = fns_for(cfg).init(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, max_len=64, batch_slots=2,
+                        prefix_sharing=True, host_blocks=4)
+    eng._state = eng._init_state()
+    k = eng._state.k
+    filled = k.at[:, 3].set(
+        jax.random.normal(jax.random.PRNGKey(1), k[:, 3].shape, k.dtype))
+    eng._state = eng._state._replace(k=filled)
+    payload = {n: np.asarray(a) for n, a in
+               eng._read_block_slices(3).items()}
+    assert set(payload) == {"k"}
+    eng._write_block(5, payload)
+    np.testing.assert_array_equal(eng._state.k[:, 5], filled[:, 3])
+    assert eng._state.v is None
+    eng.stop()

@@ -136,3 +136,63 @@ def test_paged_prefill_int8_verify_shape_compiles_for_v5e(one_chip):
 
     _assert_kernel(jax.jit(step).lower(q, kp, vp, tables, starts, starts,
                                        ks, vs).compile())
+
+
+# Moonlight-16B-A3B's latent pool at the reasoning cell's shapes: 32 slots,
+# 161-page tables (2,576 rows), one 640-lane entry per token (the 576-wide
+# latent and RoPE key padded to whole lane tiles), 16 query heads.
+MLA_SLOTS, MLA_MB, MLA_W, MLA_DV, MLA_H = 32, 161, 640, 512, 16
+
+
+def test_latent_paged_decode_compiles_for_v5e(one_chip):
+    pool = _sds(one_chip, (1 + MLA_SLOTS * MLA_MB, 1, BS, MLA_W),
+                jnp.bfloat16)
+    q = _sds(one_chip, (MLA_SLOTS, MLA_H, MLA_W), jnp.bfloat16)
+    tables = _sds(one_chip, (MLA_SLOTS, MLA_MB), jnp.int32)
+    lengths = _sds(one_chip, (MLA_SLOTS,), jnp.int32)
+
+    def step(q, pool, tables, lengths):
+        return paged_decode_attention(q, pool, None, tables, lengths,
+                                      scale=192 ** -0.5, v_dim=MLA_DV)
+
+    _assert_kernel(jax.jit(step).lower(q, pool, tables, lengths).compile())
+
+
+@pytest.mark.parametrize("chunk", [16, 512])
+def test_latent_paged_prefill_compiles_for_v5e(one_chip, chunk):
+    pool = _sds(one_chip, (1 + MLA_SLOTS * MLA_MB, 1, BS, MLA_W),
+                jnp.bfloat16)
+    q = _sds(one_chip, (1, chunk, MLA_H, MLA_W), jnp.bfloat16)
+    table = _sds(one_chip, (1, MLA_MB), jnp.int32)
+    one = _sds(one_chip, (1,), jnp.int32)
+
+    def step(q, pool, table, q_start, kv_len):
+        return paged_prefill_attention(q, pool, None, table, q_start, kv_len,
+                                       scale=192 ** -0.5, v_dim=MLA_DV)
+
+    _assert_kernel(jax.jit(step).lower(q, pool, table, one, one).compile())
+
+
+# The held experts' grouped SwiGLU at Moonlight's widths (16 held experts of
+# 2,048 x 1,408): a decode step's 32 tokens and a 256-token prefill call,
+# on one layer's stacks and on the 26-layer stacks read in place.
+@pytest.mark.parametrize("tokens,tm,stacked", [(32, 16, False),
+                                               (32, 16, True),
+                                               (256, 32, True)])
+def test_moe_expert_ffn_compiles_for_v5e(one_chip, tokens, tm, stacked):
+    from repro.kernels.moe_expert_ffn.kernel import moe_expert_ffn, rows_bound
+    lead = (26,) if stacked else ()
+    M = rows_bound(tokens, 6, 16, tm)
+    xs = _sds(one_chip, (M, 2048), jnp.bfloat16)
+    wg = _sds(one_chip, lead + (16, 2048, 1408), jnp.bfloat16)
+    wd = _sds(one_chip, lead + (16, 1408, 2048), jnp.bfloat16)
+    sizes = _sds(one_chip, (16,), jnp.int32)
+    layer = _sds(one_chip, (), jnp.int32)
+
+    def step(xs, wg, wu, wd, sizes, layer):
+        return moe_expert_ffn(xs, wg, wu, wd, sizes,
+                              layer=layer if stacked else None, tm=tm,
+                              group_rows=tokens)
+
+    _assert_kernel(jax.jit(step).lower(xs, wg, wg, wd, sizes,
+                                       layer).compile())
